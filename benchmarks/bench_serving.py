@@ -801,7 +801,10 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     from repro.configs import get_arch, reduced
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import Model, ModelRuntime
+
+    enable_compile_cache()
 
     n_requests = args.requests or (6 if args.smoke else 24)
     max_new = args.max_new or (4 if args.smoke else 32)

@@ -79,6 +79,8 @@ class Monitor:
         self.chaos = chaos
         self.started_at = self.clock.now()
         self.finished = False
+        # the DLQ size just before teardown purges it (see dead_letters())
+        self._dead_at_teardown = 0
         self._cheapest_applied = False
         self._last_hourly = self.started_at
         self._alarm_records: dict = {}
@@ -171,6 +173,13 @@ class Monitor:
             ticks += 1
         return report
 
+    def dead_letters(self) -> int:
+        """Messages in the dead-letter queue; after teardown, how many it
+        held when the purge wiped it."""
+        if self.finished:
+            return self._dead_at_teardown
+        return self.queue.counts()["dead"]
+
     # ------------------------------------------------------------------ teardown
     def _sweep_queue(self) -> int:
         """Batched straggler sweep: messages that became visible between
@@ -221,6 +230,7 @@ class Monitor:
             self.logs.put(
                 "monitor", f"teardown sweep deleted {pages} expired kvprefix pages"
             )
+        self._dead_at_teardown = self.queue.counts()["dead"]
         self.queue.purge()  # in-flight remnants + dead letters
         n = self.logs.export(self.store, f"logs/{self.cfg.app_name}")
         self.logs.put("monitor", f"teardown complete; exported {n} log streams")

@@ -278,7 +278,7 @@ class SimRunner:
             jobs_done=done,
             jobs_skipped=skipped,
             jobs_failed=failed,
-            dead_letters=len(self.rt.queue.dead_letters()) if not self.monitor.finished else 0,
+            dead_letters=self.monitor.dead_letters(),
             preemptions=self.preemptions,
             idle_terminations=idle_terms,
             ticks=ticks,
@@ -380,7 +380,7 @@ class ThreadRunner:
             jobs_done=sum(w.jobs_done for w in self.workers),
             jobs_skipped=sum(w.jobs_skipped for w in self.workers),
             jobs_failed=sum(w.jobs_failed for w in self.workers),
-            dead_letters=0,
+            dead_letters=self.monitor.dead_letters(),
             preemptions=0,
             idle_terminations=idle_terms,
             ticks=ticks,

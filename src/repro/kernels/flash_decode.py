@@ -15,7 +15,10 @@ TPU mapping (same sequential-grid trick as ``flash_attention.py``): the
 page table and per-row query offsets are *scalar-prefetch* operands
 (``pltpu.PrefetchScalarGridSpec``), so each KV ``BlockSpec`` index map
 dereferences ``page_table[b, j]`` to DMA the right physical page for
-grid step ``(b, kv_head, j)``.  The last grid dimension walks a row's
+grid step ``(b, kv_head, j)``.  The pool is stored head-major (see the
+layout contract below), so one grid step's KV block is one contiguous
+``(page_size, width)`` tile whose last two dims are whole array dims, as
+Mosaic's tiling asks.  The last grid dimension walks a row's
 logical pages in order; the online-softmax state ``(m, l, acc)`` lives
 in VMEM scratch and carries across steps.  Pages whose logical positions
 lie entirely after the row's last query — including unallocated pages,
@@ -31,16 +34,26 @@ every logical position ``< offset + T`` is backed by an allocated,
 written page (allocate-on-write), and everything at or beyond the write
 frontier is masked.
 
-Layout contract (GQA without repeating KV): callers fold queries
-*group-major* to ``(B, Hkv, G*T, dk)`` — fold index ``i = g*T + t`` —
-so all ``G`` query heads of a KV group share one grid step.  MLA's
-absorbed decode is the ``Hkv=1`` case with ``dk = kv_lora_rank +
+Layout contract (GQA without repeating KV): a KV pool is head-major,
+``(n_pages, Hkv / r, page_size, r * hd)``: each row holds one position
+of ``r = heads_per_row(Hkv, hd)`` heads side by side, so that a row
+fills the 128 lanes of a TPU vector register where the head count
+allows.  (With ``hd = 64`` unpacked rows would be half padding, and XLA
+then stores the pool with pages as its minor dim, which the kernel can
+only read after a relayout copy of the whole layer pool.)  This kernel
+sees one head group per grid step: queries folded *group-major* to
+``(B, Hkv / r, QL, r * hd)`` — ``ops.paged_attention`` zero-fills each
+query outside its own head's lanes, so a full-width dot scores exactly
+that head, and keeps each head's own lanes of the output.  MLA's
+absorbed decode is the ``Hkv = 1`` case with ``hd = kv_lora_rank +
 rope_head_dim`` and values read from the first ``v_width`` columns of
-the (shared) KV page (``ops.paged_attention`` handles both layouts).
+the (shared) KV page.
 
 Rows with no attendable positions (e.g. parked slots whose pages were
-freed) produce zeros, not NaNs.  Validated on CPU in interpret mode
-against ``ref.paged_attention_reference``.
+freed) produce zeros, not NaNs.  The kernel is compiled by Mosaic for
+TPU v5e (``tests/test_tpu_compile.py`` compiles it for a described chip)
+and validated on CPU in interpret mode against
+``ref.paged_attention_reference``.
 """
 
 from __future__ import annotations
@@ -55,6 +68,38 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+LANES = 128  # width of a TPU vector register row
+
+
+def heads_per_row(n_kv_heads: int, head_dim: int) -> int:
+    """KV heads packed side by side in one pool row: as many as fill a
+    vector register row, and a divisor of ``n_kv_heads``."""
+    return math.gcd(n_kv_heads, max(1, LANES // head_dim))
+
+
+def gather_pages(pages: jax.Array, page_table: jax.Array, head_dim: int) -> jax.Array:
+    """The rows' logical caches ``(B, P * page_size, Hkv, head_dim)`` from a
+    head-major pool ``(n_pages, Hkv / r, page_size, r * head_dim)``, through
+    the page table (sentinel entries clamped to a real page; callers mask
+    them).  Gathers only the rows' own pages."""
+    n_pages, hg, ps, width = pages.shape
+    r = width // head_dim
+    b, P = page_table.shape
+    x = pages[jnp.minimum(page_table, n_pages - 1)]  # (B, P, Hkv/r, ps, r*hd)
+    x = x.reshape(b, P, hg, ps, r, head_dim).transpose(0, 1, 3, 2, 4, 5)
+    return x.reshape(b, P * ps, hg * r, head_dim)
+
+
+def write_pages(pages: jax.Array, page_ids: jax.Array, slots: jax.Array,
+                x: jax.Array) -> jax.Array:
+    """Write ``x[..., Hkv, hd]`` at ``(page_ids, slots)`` of a head-major
+    pool.  Each update is one whole pool row, the pool's minor dim, so XLA
+    keeps the pool in the layout the kernel reads.  Out-of-bounds page ids
+    (the unallocated sentinel) are dropped."""
+    hg = pages.shape[1]
+    rows = x.reshape(x.shape[:-2] + (hg, -1))  # r heads side by side per row
+    idx = (page_ids[..., None], jnp.arange(hg), slots[..., None])
+    return pages.at[idx].set(rows.astype(pages.dtype))
 
 
 def _paged_kernel(
@@ -63,8 +108,8 @@ def _paged_kernel(
     off_ref,  # (B,) int32 absolute position of each row's first query
     # blocked operands
     q_ref,  # (1, 1, QL, dk)
-    k_ref,  # (1, page_size, 1, dk)
-    v_ref,  # (1, page_size, 1, dv_store)
+    k_ref,  # (1, 1, page_size, dk)
+    v_ref,  # (1, 1, page_size, dv_store)
     o_ref,  # (1, 1, QL, dv)
     # scratch
     m_scr,  # (QL, 1) f32
@@ -98,8 +143,8 @@ def _paged_kernel(
     @pl.when(run)
     def _body():
         q = q_ref[0, 0].astype(jnp.float32) * scale  # (QL, dk)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (ps, dk)
-        v = v_ref[0, :, 0, :v_width].astype(jnp.float32)  # (ps, dv)
+        k = k_ref[0, 0].astype(jnp.float32)  # (ps, dk)
+        v = v_ref[0, 0, :, :v_width].astype(jnp.float32)  # (ps, dv)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # (QL, ps)
@@ -132,9 +177,9 @@ def _paged_kernel(
 
 
 def paged_flash_attention_folded(
-    q: jax.Array,  # (B, Hkv, QL, dk) group-major fold, QL = G * T
-    k_pages: jax.Array,  # (n_pages, page_size, Hkv, dk)
-    v_pages: jax.Array,  # (n_pages, page_size, Hkv, dv_store)
+    q: jax.Array,  # (B, Hg, QL, dk) group-major fold, QL a multiple of T
+    k_pages: jax.Array,  # (n_pages, Hg, page_size, dk)
+    v_pages: jax.Array,  # (n_pages, Hg, page_size, dv_store)
     page_table: jax.Array,  # (B, P) int32; entries >= n_pages = unallocated
     offsets: jax.Array,  # (B,) int32 absolute position of first query token
     *,
@@ -143,9 +188,9 @@ def paged_flash_attention_folded(
     softcap: float = 0.0,
     v_width: int = 0,
     interpret: bool = False,
-) -> jax.Array:  # (B, Hkv, QL, dv)
+) -> jax.Array:  # (B, Hg, QL, dv)
     b, hkv, ql, dk = q.shape
-    n_pages, page_size = k_pages.shape[0], k_pages.shape[1]
+    n_pages, page_size = k_pages.shape[0], k_pages.shape[2]
     pages_per_slot = page_table.shape[1]
     dv_store = v_pages.shape[-1]
     dv = v_width or dv_store
@@ -168,15 +213,15 @@ def paged_flash_attention_folded(
 
     def page_map(bb, hh, jj, table, off):
         # clamp the sentinel in bounds: skipped pages still DMA *something*
-        return (jnp.minimum(table[bb, jj], n_pages - 1), 0, hh, 0)
+        return (jnp.minimum(table[bb, jj], n_pages - 1), hh, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, hkv, pages_per_slot),
         in_specs=[
             pl.BlockSpec((1, 1, ql, dk), lambda bb, hh, jj, table, off: (bb, hh, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, dk), page_map),
-            pl.BlockSpec((1, page_size, 1, dv_store), page_map),
+            pl.BlockSpec((1, 1, page_size, dk), page_map),
+            pl.BlockSpec((1, 1, page_size, dv_store), page_map),
         ],
         out_specs=pl.BlockSpec(
             (1, 1, ql, dv), lambda bb, hh, jj, table, off: (bb, hh, 0, 0)

@@ -1,13 +1,15 @@
 """Jit-ready wrappers around the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container), so the same call
-sites run the kernel bodies in Python on CPU for validation and compile
-the real mosaic kernels on TPU.
+On TPU the kernels are compiled by Mosaic.  ``interpret`` defaults to
+True on any other backend: interpret mode runs the kernel bodies as
+plain JAX ops, and exists for the CPU tests only (it says nothing about
+whether Mosaic accepts a kernel, nor about its speed).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -53,8 +55,8 @@ def flash_attention(
 
 def paged_attention(
     q: jax.Array,  # (B, T, H, dk) — model layout, RoPE already applied
-    k_pages: jax.Array,  # (n_pages, page_size, Hkv, dk)
-    v_pages: jax.Array,  # (n_pages, page_size, Hkv, dv_store)
+    k_pages: jax.Array,  # (n_pages, Hkv / r, page_size, r * dk) — head-major pool
+    v_pages: jax.Array,  # (n_pages, Hkv / r, page_size, r * dv) (or MLA's shared page)
     page_table: jax.Array,  # (B, P) int32; entries >= n_pages = unallocated
     offsets: jax.Array,  # (B,) absolute position of each row's first token
     *,
@@ -66,18 +68,26 @@ def paged_attention(
     """Flash-decode / chunk-extend against a paged KV cache.
 
     Grouped-query layout: KV heads are NOT repeated; the kernel processes
-    one (row, kv head) pair per grid step with all its query heads folded
-    group-major into the query block.  ``T = 1`` is decode, ``T > 1`` the
-    chunk-extend used by fused prefill.  MLA's absorbed form is the
-    ``Hkv = 1`` case (``v_width`` selects the latent columns of the
-    shared KV page).  Query ``t`` of row ``b`` sits at absolute position
-    ``offsets[b] + t``; the engine's allocate-on-write invariant makes
-    the causal mask exact (see ``flash_decode``).
+    one (row, kv head group) pair per grid step with all its query heads
+    folded group-major into the query block.  A pool row holds ``r``
+    heads side by side (``flash_decode.heads_per_row``; ``r`` is read off
+    the pool's width): each query is zero outside its own head's lanes,
+    so the kernel's full-width dot scores exactly that head, and each
+    head keeps its own lanes of the output.  ``T = 1`` is decode,
+    ``T > 1`` the chunk-extend used by fused prefill.  MLA's absorbed
+    form is the ``Hkv = 1`` case (``v_width`` selects the latent columns
+    of the shared KV page).  Query ``t`` of row ``b`` sits at absolute
+    position ``offsets[b] + t``; the engine's allocate-on-write invariant
+    makes the causal mask exact (see ``flash_decode``).
     """
     b, T, h, dk = q.shape
-    hkv = k_pages.shape[2]
-    g = h // hkv
-    qf = q.reshape(b, T, hkv, g, dk).transpose(0, 2, 3, 1, 4).reshape(b, hkv, g * T, dk)
+    hg = k_pages.shape[1]
+    r = k_pages.shape[-1] // dk
+    g = h // (hg * r)
+    # (B, T, Hg, r, G, dk) -> (B, Hg, r, G*T, dk), then zero-fill to r*dk lanes
+    qf = q.reshape(b, T, hg, r, g, dk).transpose(0, 2, 3, 4, 1, 5).reshape(b, hg, r, g * T, dk)
+    own = jnp.eye(r, dtype=bool)[:, None, :, None]  # (r, 1, r, 1): head j -> lanes j
+    qf = jnp.where(own, qf[:, :, :, :, None, :], 0).reshape(b, hg, r * g * T, r * dk)
     out = paged_flash_attention_folded(
         qf,
         k_pages,
@@ -85,13 +95,16 @@ def paged_attention(
         page_table,
         offsets,
         tokens_per_row=T,
-        scale=scale,
+        scale=scale if scale is not None else 1.0 / math.sqrt(dk),
         softcap=softcap,
         v_width=v_width,
         interpret=_default_interpret(interpret),
-    )  # (B, Hkv, G*T, dv)
-    dv = out.shape[-1]
-    return out.reshape(b, hkv, g, T, dv).transpose(0, 3, 1, 2, 4).reshape(b, T, h, dv)
+    )  # (B, Hg, r*G*T, r*dv)
+    dv = out.shape[-1] // r
+    out = jnp.diagonal(out.reshape(b, hg, r, g * T, r, dv), axis1=2, axis2=4)
+    # (B, Hg, G*T, dv, r) -> (B, T, Hg, r, G, dv)
+    out = out.reshape(b, hg, g, T, dv, r).transpose(0, 3, 1, 5, 2, 4)
+    return out.reshape(b, T, h, dv)
 
 
 def paged_verify(

@@ -35,8 +35,8 @@ def attention_reference(
 
 def paged_attention_reference(
     q: jax.Array,  # (B, T, H, dk)
-    k_pages: jax.Array,  # (n_pages, page_size, Hkv, dk)
-    v_pages: jax.Array,  # (n_pages, page_size, Hkv, dv_store)
+    k_pages: jax.Array,  # (n_pages, Hkv / r, page_size, r * dk) — head-major pool
+    v_pages: jax.Array,  # (n_pages, Hkv / r, page_size, r * dv)
     page_table: jax.Array,  # (B, P) int32; entries >= n_pages = unallocated
     offsets: jax.Array,  # (B,)
     *,
@@ -52,15 +52,23 @@ def paged_attention_reference(
     with zero attendable positions return zeros (matching the kernel's
     all-pages-skipped writeback).
     """
-    n_pages, ps, hkv, dk = k_pages.shape
-    b, T, h, _ = q.shape
+    n_pages, hg, ps, width = k_pages.shape
+    b, T, h, dk = q.shape
     P = page_table.shape[1]
+    r = width // dk
+    hkv = hg * r
     g = h // hkv
     if not scale:
         scale = 1.0 / math.sqrt(dk)
     safe = jnp.minimum(page_table, n_pages - 1)
-    k = k_pages[safe].reshape(b, P * ps, hkv, dk).astype(jnp.float32)
-    v = v_pages[safe].reshape(b, P * ps, hkv, -1).astype(jnp.float32)
+
+    def logical(pages):  # (B, P, Hkv/r, ps, r*d) -> (B, P*ps, Hkv, d)
+        x = pages[safe]
+        x = x.reshape(b, P, hg, ps, r, -1).transpose(0, 1, 3, 2, 4, 5)
+        return x.reshape(b, P * ps, hkv, -1).astype(jnp.float32)
+
+    k = logical(k_pages)
+    v = logical(v_pages)
     if v_width:
         v = v[..., :v_width]
     qg = q.reshape(b, T, hkv, g, dk).astype(jnp.float32)

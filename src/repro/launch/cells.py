@@ -195,8 +195,8 @@ def cache_specs(cfg: ArchConfig, cache_shapes, rules, mesh) -> Any:
         name = path[-1] if path else ""
         logical: Tuple[Optional[str], ...]
         if name in ("k_pages", "v_pages"):
-            #   k_pages/v_pages: (L, n_pages, ps, Hkv, hd)
-            logical = (None, "kv_pages", None, "kv_heads", None)
+            #   k_pages/v_pages: (L, n_pages, Hkv, ps, hd)
+            logical = (None, "kv_pages", "kv_heads", None, None)
         elif name == "kv_pages":
             #   MLA pool: (L, n_pages, ps, r+qr)
             logical = (None, "kv_pages", None, None)

@@ -217,8 +217,9 @@ def apply_mla_paged(
         from repro.kernels import ops as kops
 
         q_eff = jnp.concatenate([q_lat, q_rope], axis=-1)  # (b,T,h,r+qr)
+        pool = kv_pages[:, None]  # (n_pages, 1, ps, r+qr): Hkv=1, no copy
         ctx = kops.paged_attention(
-            q_eff, kv_pages[:, :, None, :], kv_pages[:, :, None, :],
+            q_eff, pool, pool,
             page_table, positions[:, 0], scale=scale, v_width=r,
         )  # (b,T,h,r)
     else:

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.kernels.flash_decode import gather_pages, heads_per_row, write_pages
 from repro.models import mla as mla_mod
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
@@ -57,8 +58,9 @@ class ModelRuntime:
     attn_impl: str = "auto"  # auto | direct | chunked | kernel
     attn_chunk: int = 1024
     # paged-cache attention: "kernel" = Pallas flash-decode/chunk-extend
-    # through the page table (interpret mode off-TPU), "jnp" = gather the
-    # pages and reuse the dense attention path (the CPU-fast reference)
+    # through the page table (compiled by Mosaic on TPU; interpret mode,
+    # elsewhere, is for the CPU tests only), "jnp" = gather the pages and
+    # reuse the dense attention path (the CPU-fast reference)
     paged_attn_impl: str = "auto"  # auto | kernel | jnp
     moe_strategy: str = "capacity"
     use_ssd_kernel: bool = False
@@ -316,7 +318,7 @@ def _attn_decode(
 # ------------------------------------------------------- paged KV attention
 def _paged_gqa(
     q: jax.Array,  # (b, T, H, hd)
-    k_pages: jax.Array,  # (n_pages, ps, Hkv, hd)
+    k_pages: jax.Array,  # (n_pages, Hkv/r, ps, r*hd) — head-major
     v_pages: jax.Array,
     page_table: jax.Array,  # (b, P) int32; >= n_pages = unallocated
     q_positions: jax.Array,  # (b, T)
@@ -340,12 +342,12 @@ def _paged_gqa(
             q, k_pages, v_pages, page_table, q_positions[:, 0],
             softcap=cfg.attn_logit_softcap,
         )
-    n_pages, ps, hkv, hd = k_pages.shape
+    n_pages, ps = k_pages.shape[0], k_pages.shape[2]
+    hd = q.shape[-1]
     b = q.shape[0]
     P = page_table.shape[1]
-    safe = jnp.minimum(page_table, n_pages - 1)
-    kf = k_pages[safe].reshape(b, P * ps, hkv, hd)
-    vf = v_pages[safe].reshape(b, P * ps, hkv, hd)
+    kf = gather_pages(k_pages, page_table, hd)
+    vf = gather_pages(v_pages, page_table, hd)
     kv_pos = jnp.broadcast_to(jnp.arange(P * ps, dtype=jnp.int32)[None], (b, P * ps))
     # unallocated pages gather garbage from the clamped physical page; the
     # allocator keeps them past every query's frontier, but masking them
@@ -361,7 +363,7 @@ def _paged_gqa(
 def _attn_decode_paged(
     p: Params,
     x: jax.Array,  # (b,1,d)
-    k_pages: jax.Array,  # (n_pages, ps, hkv, hd) — this layer's page pool
+    k_pages: jax.Array,  # (n_pages, hkv/r, ps, r*hd) — this layer's page pool
     v_pages: jax.Array,
     page_table: jax.Array,  # (b, P)
     pos: jax.Array,  # (b,) per-row cache positions
@@ -384,13 +386,12 @@ def _attn_decode_paged(
     if use_rope:
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
-    n_pages, ps = k_pages.shape[0], k_pages.shape[1]
+    ps = k_pages.shape[2]
     P = page_table.shape[1]
     rows = jnp.arange(b)
     phys = page_table[rows, jnp.minimum(pos_v // ps, P - 1)]  # (b,) OOB = dropped
-    off = pos_v % ps
-    k_pages = k_pages.at[phys, off].set(k[:, 0].astype(k_pages.dtype))
-    v_pages = v_pages.at[phys, off].set(v[:, 0].astype(v_pages.dtype))
+    k_pages = write_pages(k_pages, phys, pos_v % ps, k[:, 0])
+    v_pages = write_pages(v_pages, phys, pos_v % ps, v[:, 0])
     out = _paged_gqa(q, k_pages, v_pages, page_table, positions, cfg, rt)
     out = out.reshape(b, 1, h * hd)
     out = shard(out, "act_batch", "seq", "act_heads")
@@ -426,14 +427,14 @@ def _attn_extend_paged(
     if use_rope:
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
-    n_pages, ps = k_pages.shape[0], k_pages.shape[1]
+    n_pages, ps = k_pages.shape[0], k_pages.shape[2]
     P = page_table.shape[1]
     rows = jnp.arange(b)[:, None]
     wp = page_table[rows, jnp.minimum(positions // ps, P - 1)]  # (b,T)
     if valid is not None:
         wp = jnp.where(valid, wp, n_pages)  # out of bounds -> dropped
-    k_pages = k_pages.at[wp, positions % ps].set(k.astype(k_pages.dtype))
-    v_pages = v_pages.at[wp, positions % ps].set(v.astype(v_pages.dtype))
+    k_pages = write_pages(k_pages, wp, positions % ps, k)
+    v_pages = write_pages(v_pages, wp, positions % ps, v)
     out = _paged_gqa(q, k_pages, v_pages, page_table, positions, cfg, rt)
     out = out.reshape(b, T, h * hd)
     out = shard(out, "act_batch", "seq", "act_heads")
@@ -840,7 +841,11 @@ class Model:
 
         ``paged=True`` replaces the per-slot dense ``max_len`` reservation
         with a shared pool of ``n_pages`` fixed-size pages plus a per-slot
-        ``page_table`` (``(batch, max_len/page_size)`` int32).  Table
+        ``page_table`` (``(batch, max_len/page_size)`` int32).  K/V pools
+        are head-major, ``(L, n_pages, Hkv/r, page_size, r*hd)`` with
+        ``r = heads_per_row(Hkv, hd)`` heads side by side in a row — the
+        layout the Pallas paged kernel reads (``kernels/flash_decode.py``);
+        the MLA latent pool is ``(L, n_pages, page_size, width)``.  Table
         entries hold the out-of-bounds sentinel ``n_pages`` until the
         owner (the serving engine's allocator) backs them with a physical
         page; cache memory then scales with tokens actually resident
@@ -866,8 +871,10 @@ class Model:
                 width = cfg.kv_lora_rank + cfg.rope_head_dim
                 cache["kv_pages"] = jnp.zeros((L, pool, ps, width), dtype)
             else:
-                cache["k_pages"] = jnp.zeros((L, pool, ps, cfg.n_kv_heads, hd), dtype)
-                cache["v_pages"] = jnp.zeros((L, pool, ps, cfg.n_kv_heads, hd), dtype)
+                r = heads_per_row(cfg.n_kv_heads, hd)
+                shape = (L, pool, cfg.n_kv_heads // r, ps, r * hd)
+                cache["k_pages"] = jnp.zeros(shape, dtype)
+                cache["v_pages"] = jnp.zeros(shape, dtype)
             return cache
         if cfg.family == "ssm":
             cache["state"] = _stack_states(ssm_mod.mamba2_decode_state(cfg, batch, dtype), L)

@@ -164,6 +164,8 @@ def test_poison_jobs_end_in_dlq_without_wedging(tmp_path):
     summary = runner.run(max_ticks=50)
     assert summary.jobs_done == 0
     assert rt.monitor.finished, "cluster must tear down despite poison jobs"
+    # teardown purges the DLQ, but the summary still counts what it held
+    assert summary.dead_letters == 2
 
 
 def test_teardown_sweeps_expired_kvprefix_pages(tmp_path):
@@ -217,3 +219,56 @@ def test_idle_alarm_terminates_stalled_instance(tmp_path):
         report = monitor.tick()
     assert inst.state == InstanceState.TERMINATED
     assert inst.terminate_reason == "idle-alarm"
+
+
+def test_thread_runner_counts_dead_letters(tmp_path):
+    """A payload exception that dead-letters its job shows in the real
+    runner's summary (the DLQ is purged at teardown, before it returns)."""
+    from repro.core import ThreadRunner
+
+    rt = DSRuntime(
+        DSConfig(app_name="T", payload="always-fails", cluster_machines=1,
+                 machine_type=["sim.large"], machine_price=1.0,
+                 sqs_message_visibility=0.5, max_receive_count=1,
+                 check_if_done=False),
+        store_root=str(tmp_path / "store"),
+    )
+    rt.setup()
+    rt.submit_job(JobFile(groups=[{"g": 0}, {"g": 1}]))
+    rt.start_cluster(FleetFile(startup_seconds=0.0))
+    summary = ThreadRunner(rt).run()
+    assert summary.jobs_done == 0 and summary.jobs_failed == 2
+    assert summary.dead_letters == 2
+
+
+@pytest.mark.parametrize("payload,rc", [("noop-sleep", 0), ("always-fails", 1)])
+def test_worker_host_exit_code_reports_dead_letters(tmp_path, payload, rc):
+    """``worker_host`` exits non-zero exactly when a job dead-lettered."""
+    from repro.launch.worker_host import run_worker_host
+
+    workdir = tmp_path / "w"
+    workdir.mkdir()
+    cfg = DSConfig(app_name="T", payload=payload, cluster_machines=1,
+                   machine_type=["sim.large"], machine_price=1.0,
+                   sqs_message_visibility=0.5, max_receive_count=1,
+                   check_if_done=False)
+    (workdir / "config.json").write_text(cfg.to_json())
+    (workdir / "fleet.json").write_text(FleetFile(startup_seconds=0.0).to_json())
+    rt = DSRuntime(cfg, store_root=str(workdir / "store"))
+    rt.setup()
+    rt.submit_job(JobFile(groups=[{"g": 0}]))
+    assert run_worker_host(str(workdir)) == rc
+    summary = rt.store.get_json("summary/T.json")
+    assert summary["dead_letters"] == rc
+
+
+def test_run_ds_commands_do_not_import_jax():
+    """The control-node commands stay off JAX: only the worker host they
+    start may hold the accelerator."""
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    code = "import sys, repro.launch.run_ds; sys.exit(int('jax' in sys.modules))"
+    env = dict(os.environ, PYTHONPATH=src)
+    assert subprocess.run([sys.executable, "-c", code], env=env).returncode == 0

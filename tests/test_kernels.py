@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.flash_decode import heads_per_row
 
 KEY = jax.random.PRNGKey(0)
 
@@ -70,13 +71,14 @@ def test_flash_attention_model_layout():
 
 
 # ------------------------------------------------- paged flash decode/extend
-def _paged_setup(B, Hkv, dk, ps, P, n_pages, offsets, dtype=jnp.float32, seed=0):
-    """Random page pools + a permuted page table backing each row's
-    positions [0, offsets[b] + T) — the allocator invariant the serving
-    engine maintains."""
+def _paged_setup(B, Hkv, dk, ps, P, n_pages, offsets, dtype=jnp.float32, seed=0, r=1):
+    """Random head-major page pools ``(n_pages, Hkv/r, ps, r*dk)``, ``r``
+    heads side by side per row — the layout ``Model.init_cache(paged=True)``
+    allocates per layer."""
     rng = np.random.default_rng(seed)
-    k_pages = jnp.asarray(rng.standard_normal((n_pages, ps, Hkv, dk)), dtype)
-    v_pages = jnp.asarray(rng.standard_normal((n_pages, ps, Hkv, dk)), dtype)
+    shape = (n_pages, Hkv // r, ps, r * dk)
+    k_pages = jnp.asarray(rng.standard_normal(shape), dtype)
+    v_pages = jnp.asarray(rng.standard_normal(shape), dtype)
     return k_pages, v_pages
 
 
@@ -94,13 +96,14 @@ def _alloc_table(B, P, n_pages, frontiers, ps, seed=1):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("T", [1, 4])  # flash-decode and chunk-extend
-@pytest.mark.parametrize("hkv,g", [(2, 4), (1, 8)])  # GQA and MLA-style Hkv=1
-def test_paged_attention_vs_reference(T, hkv, g, dtype):
+# GQA with one or two heads per pool row, and MLA-style Hkv=1
+@pytest.mark.parametrize("hkv,g,r", [(2, 4, 1), (2, 4, 2), (1, 8, 1)])
+def test_paged_attention_vs_reference(T, hkv, g, r, dtype):
     B, dk, ps, P = 3, 32, 8, 4
     n_pages = 10
     h = hkv * g
     offsets = np.asarray([5, 0, 9], np.int32)  # ragged rows
-    k_pages, v_pages = _paged_setup(B, hkv, dk, ps, P, n_pages, offsets, dtype)
+    k_pages, v_pages = _paged_setup(B, hkv, dk, ps, P, n_pages, offsets, dtype, r=r)
     table = _alloc_table(B, P, n_pages, offsets + T, ps)
     q = jnp.asarray(
         np.random.default_rng(2).standard_normal((B, T, h, dk)), dtype
@@ -125,7 +128,7 @@ def test_paged_verify_vs_reference(k):
     hkv, g = 2, 4
     T = k + 1
     offsets = np.asarray([5, 0, 9], np.int32)
-    k_pages, v_pages = _paged_setup(B, hkv, dk, ps, P, n_pages, offsets)
+    k_pages, v_pages = _paged_setup(B, hkv, dk, ps, P, n_pages, offsets, r=2)
     table = _alloc_table(B, P, n_pages, offsets + T, ps)
     q = jnp.asarray(
         np.random.default_rng(7).standard_normal((B, T, hkv * g, dk)),
@@ -171,8 +174,13 @@ def test_paged_attention_matches_contiguous_reference():
     )
     kv = rng.standard_normal((B, seq, h, dk)).astype(np.float32)
     vv = rng.standard_normal((B, seq, h, dk)).astype(np.float32)
-    k_pages = jnp.asarray(kv.reshape(B * P, ps, h, dk))
-    v_pages = jnp.asarray(vv.reshape(B * P, ps, h, dk))
+    r = heads_per_row(h, dk)  # 4 heads of 16 lanes per pool row
+
+    def pages(x):  # (B, seq, h, dk) -> head-major (B*P, h/r, ps, r*dk)
+        x = x.reshape(B * P, ps, h // r, r * dk).transpose(0, 2, 1, 3)
+        return jnp.asarray(x)
+
+    k_pages, v_pages = pages(kv), pages(vv)
     q = jnp.asarray(rng.standard_normal((B, T, h, dk)), jnp.float32)
     offsets = jnp.full((B,), seq - T, jnp.int32)  # chunk = the last T tokens
     out = ops.paged_attention(q, k_pages, v_pages, table, offsets, interpret=True)
@@ -191,7 +199,7 @@ def test_paged_attention_parked_rows_are_finite_zero():
     poison the dispatch."""
     B, T, h, dk, ps, P, n_pages = 2, 1, 4, 16, 8, 4, 6
     rng = np.random.default_rng(4)
-    k_pages = jnp.asarray(rng.standard_normal((n_pages, ps, h, dk)), jnp.float32)
+    k_pages = jnp.asarray(rng.standard_normal((n_pages, h, ps, dk)), jnp.float32)
     table = np.full((B, P), n_pages, np.int32)
     table[0, 0] = 2  # row 0 live, row 1 parked
     q = jnp.asarray(rng.standard_normal((B, T, h, dk)), jnp.float32)
