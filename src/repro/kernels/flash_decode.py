@@ -237,6 +237,7 @@ def paged_flash_attention_folded(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, ql, dv), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(
         page_table.astype(jnp.int32),
         offsets.astype(jnp.int32),

@@ -41,6 +41,7 @@ from typing import Callable, Dict, List, Optional
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.prefix_store import PrefixStore
@@ -310,9 +311,10 @@ class KVCacheManager:
     def _copy_page(self, src: int, dst: int) -> None:
         """Copy-on-write: duplicate one physical page across every layer
         and pool leaf (one device op per leaf, outside the jitted step)."""
-        for name, leaf in self.cache.items():
-            if name.endswith("_pages"):
-                self.cache[name] = leaf.at[:, dst].set(leaf[:, src])
+        with TraceAnnotation("serve.pages.cow"):
+            for name, leaf in self.cache.items():
+                if name.endswith("_pages"):
+                    self.cache[name] = leaf.at[:, dst].set(leaf[:, src])
 
     def ensure_pages(
         self, row: int, n_tokens: int, write_start: Optional[int] = None
@@ -581,11 +583,12 @@ class KVCacheManager:
         if self.store is not None:
             n_chunks = min(len(prompt) // self.page_size, self.pages_per_slot)
             hydrate = self._hydrate_demand if demand else self._hydrate
-            if len(path) < n_chunks and hydrate(
-                prompt, [n.page for n in path], n_chunks
-            ):
-                # now extended locally (possibly exposing a new partial)
-                path, pnode, plen = lookup()
+            if len(path) < n_chunks:
+                with TraceAnnotation("serve.store.hydrate"):
+                    hydrated = hydrate(prompt, [n.page for n in path], n_chunks)
+                if hydrated:
+                    # now extended locally (possibly exposing a new partial)
+                    path, pnode, plen = lookup()
         path = path[: self.pages_per_slot]
         matched = len(path) * self.page_size
         eff = min(matched, len(prompt) - 1)
@@ -622,7 +625,8 @@ class KVCacheManager:
                 if path else self.store.root_key()
             )
             tkey = self.store.child_key(parent, rem)
-            arrays = self.store.fetch(tkey, self._page_like())
+            with TraceAnnotation("serve.store.hydrate"):
+                arrays = self.store.fetch(tkey, self._page_like())
             self._sync_store_stats()
             if arrays is not None:
                 pid = self._take_free_page()
@@ -720,12 +724,14 @@ class KVCacheManager:
         n_full = min(len(prompt) // self.page_size, len(self._slot_pages[row]))
         if n_full == 0:
             return
-        pages = self._slot_pages[row][:n_full]
-        adopted = self.prefix.insert(prompt, pages)
-        for pid in adopted:
-            self._incref(pid)
-        if self.store is not None:
-            self._publish(prompt, pages, n_full)
+        with TraceAnnotation("serve.prefix_insert"):
+            pages = self._slot_pages[row][:n_full]
+            adopted = self.prefix.insert(prompt, pages)
+            for pid in adopted:
+                self._incref(pid)
+            if self.store is not None:
+                with TraceAnnotation("serve.store.publish"):
+                    self._publish(prompt, pages, n_full)
 
     # ----------------------------------------------- cross-host prefix store
     def _pool_leaves(self) -> Dict[str, object]:
@@ -798,7 +804,8 @@ class KVCacheManager:
         before = self.stats.prefix_store_pages_published
         n_full = min(len(tokens) // ps, len(pages))
         if n_full:
-            self._publish(tokens, pages[:n_full], n_full)
+            with TraceAnnotation("serve.store.publish"):
+                self._publish(tokens, pages[:n_full], n_full)
         tail = tokens[n_full * ps:]
         if tail and n_full < len(pages):
             parent = (
@@ -1057,5 +1064,6 @@ class KVCacheManager:
         if self.cache_mode == "paged" and self._table_dirty:
             import jax.numpy as jnp
 
-            self.cache["page_table"] = jnp.asarray(self._table)
+            with TraceAnnotation("serve.push_table"):
+                self.cache["page_table"] = jnp.asarray(self._table)
             self._table_dirty = False

@@ -52,6 +52,12 @@ dispatches-per-token metric.  ``steps_executed`` keeps its seed meaning
 :class:`repro.serving.types.EngineStats` block (``engine.stats``); the
 flat attribute aliases below (``engine.tokens_emitted`` and friends)
 are kept as the stable public surface.
+
+Tracing: each activity of a tick (admission, page reservation, input
+building, the page-table upload, each dispatch, its read-back, per-row
+accept) runs inside a ``serve.*`` ``jax.profiler.TraceAnnotation``, so
+a profiler trace puts each stretch of device idle time down to the host
+activity beside it (``docs/serving.md``, "Tracing").
 """
 
 from __future__ import annotations
@@ -61,6 +67,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models import Model
 from repro.serving.cache_manager import KVCacheManager
@@ -360,10 +367,11 @@ class ServeEngine:
 
     # ------------------------------------------------------------- intake
     def submit(self, reqs: List[Request]) -> None:
-        self.scheduler.submit(reqs)
-        # adaptive pool sizing sees the queue depth at submit (the caller
-        # no longer guesses total_pages)
-        self.cache_mgr.on_submit(self.scheduler.pending)
+        with TraceAnnotation("serve.submit", n=len(reqs)):
+            self.scheduler.submit(reqs)
+            # adaptive pool sizing sees the queue depth at submit (the
+            # caller no longer guesses total_pages)
+            self.cache_mgr.on_submit(self.scheduler.pending)
 
     # ------------------------------------- work-preserving recovery seam
     def checkpoint_slot(self, row: int) -> Optional[Dict]:
@@ -389,7 +397,8 @@ class ServeEngine:
         # chained resumes never double-extend
         base = len(req.prompt) - req.resume_base
         resident = list(req.prompt[:base]) + req.output[:-1]
-        self.cache_mgr.publish_generation(row, resident)
+        with TraceAnnotation("serve.prefix_insert"):
+            self.cache_mgr.publish_generation(row, resident)
         self.stats.checkpoints_published += 1
         return {
             "uid": req.uid,
@@ -483,63 +492,39 @@ class ServeEngine:
         part of why the fused path exists; use grouped mode only on
         attention-family models.
         """
-        self.scheduler.begin_tick()
-        if not self.scheduler.has_active():
-            return 0
-        emitted = 0
-        if self._use_prefill:
-            emitted += self._ingest_prompts()
-        if self.worker_role == "prefill":
-            # prefill-role tick: ingest only.  Each prompt finishes at
-            # ingest completion (published + handed off, zero tokens
-            # sampled) so a decode dispatch here could only be a no-op
+        with TraceAnnotation("serve.step", tick=self.scheduler.tick + 1):
+            self.scheduler.begin_tick()
+            if not self.scheduler.has_active():
+                return 0
+            emitted = 0
+            if self._use_prefill:
+                emitted += self._ingest_prompts()
+            if self.worker_role == "prefill":
+                # prefill-role tick: ingest only.  Each prompt finishes at
+                # ingest completion (published + handed off, zero tokens
+                # sampled) so a decode dispatch here could only be a no-op
+                if os.environ.get("DS_DEBUG_INVARIANTS") == "1":
+                    self.cache_mgr.check_invariants()
+                return emitted
+            if self.dispatch_mode == "grouped":
+                emitted += self._decode_tick_grouped()
+            elif self.speculative != "off":
+                emitted += self._decode_tick_spec()
+            else:
+                emitted += self._decode_tick_fused()
             if os.environ.get("DS_DEBUG_INVARIANTS") == "1":
                 self.cache_mgr.check_invariants()
             return emitted
-        if self.dispatch_mode == "grouped":
-            emitted += self._decode_tick_grouped()
-        elif self.speculative != "off":
-            emitted += self._decode_tick_spec()
-        else:
-            emitted += self._decode_tick_fused()
-        if os.environ.get("DS_DEBUG_INVARIANTS") == "1":
-            self.cache_mgr.check_invariants()
-        return emitted
 
     # -- prompt ingestion (fused chunked prefill) ---------------------------
     def _ingest_prompts(self) -> int:
         emitted = 0
         B, C = self.max_batch, self.prefill_chunk
         slots = self.scheduler.slots
-        budget = self.scheduler.prefill_token_budget
-        left = budget  # None = unbounded: drain every prompt this tick
+        left = self.scheduler.prefill_token_budget  # None = unbounded
         while True:
-            # plan this dispatch under the remaining tick budget: per-row
-            # token counts fixed BEFORE the reservation pass below
-            plan: Dict[int, int] = {}
-            prefilling = [
-                i for i, s in enumerate(slots)
-                if s.req is not None and s.remaining_prompt
-            ]
-            if not prefilling or (left is not None and left <= 0):
-                return emitted
-            if left is None:
-                for i in prefilling:
-                    plan[i] = min(C, len(slots[i].remaining_prompt))
-            else:
-                # fair-share the remaining budget across prefilling rows
-                # (ceil of an even split each), rotating the head row by
-                # tick so a budget smaller than the row count cannot
-                # pin-starve the same rows forever — lowest-index-first
-                # would hold a short prompt hostage behind a long one
-                start = self.scheduler.tick % len(prefilling)
-                order = prefilling[start:] + prefilling[:start]
-                for idx, i in enumerate(order):
-                    share = -(-left // (len(order) - idx))
-                    n = min(C, len(slots[i].remaining_prompt), share)
-                    if n > 0:
-                        plan[i] = n
-                        left -= n
+            with TraceAnnotation("serve.inputs"):
+                plan, left = self._plan_prefill(left)
             if not plan:
                 return emitted
             if self.cache_mode == "paged":
@@ -550,11 +535,12 @@ class ServeEngine:
                 # survivor holds pages.  A dropped row's slot.req is
                 # None: yielded and preempted rows alike are requeued at
                 # the clean seam and rerun byte-identically
-                for i, n in plan.items():
-                    s = slots[i]
-                    if s.req is not None and s.remaining_prompt:
-                        self.cache_mgr.ensure_pages(i, s.pos + n,
-                                                    write_start=s.pos)
+                with TraceAnnotation("serve.pages"):
+                    for i, n in plan.items():
+                        s = slots[i]
+                        if s.req is not None and s.remaining_prompt:
+                            self.cache_mgr.ensure_pages(i, s.pos + n,
+                                                        write_start=s.pos)
             rows = [
                 i for i in plan
                 if slots[i].req is not None and slots[i].remaining_prompt
@@ -566,81 +552,133 @@ class ServeEngine:
                 left += sum(plan[i] for i in plan if i not in rows)
             if not rows:
                 return emitted
-            tokens = np.zeros((B, C), np.int32)
-            offsets = np.zeros((B,), np.int32)
-            lengths = np.zeros((B,), np.int32)
-            temps = np.zeros((B,), np.float32)
-            streams = np.zeros((B,), np.int32)
-            steps = np.zeros((B,), np.int32)
-            stops = np.full((B,), -1, np.int32)
-            max_news = np.full((B,), 1 << 30, np.int32)
-            for i in rows:
-                slot = slots[i]
-                n = min(plan[i], len(slot.remaining_prompt))
-                tokens[i, :n] = slot.remaining_prompt[:n]
-                offsets[i] = slot.pos
-                lengths[i] = n
-                temps[i] = slot.req.temperature
-                streams[i] = slot.req.sample_stream
-                # a checkpoint resume admits with pre-seeded output, so
-                # the prefill-completion sample/done mask must start at
-                # the emission count, not 0 (no-op for fresh requests)
-                steps[i] = len(slot.req.output)
-                if slot.req.stop_token is not None:
-                    stops[i] = slot.req.stop_token
-                max_news[i] = slot.req.max_new_tokens
+            with TraceAnnotation("serve.inputs"):
+                tokens = np.zeros((B, C), np.int32)
+                offsets = np.zeros((B,), np.int32)
+                lengths = np.zeros((B,), np.int32)
+                temps = np.zeros((B,), np.float32)
+                streams = np.zeros((B,), np.int32)
+                steps = np.zeros((B,), np.int32)
+                stops = np.full((B,), -1, np.int32)
+                max_news = np.full((B,), 1 << 30, np.int32)
+                n_tokens = 0
+                for i in rows:
+                    slot = slots[i]
+                    n = min(plan[i], len(slot.remaining_prompt))
+                    tokens[i, :n] = slot.remaining_prompt[:n]
+                    offsets[i] = slot.pos
+                    lengths[i] = n
+                    n_tokens += n
+                    temps[i] = slot.req.temperature
+                    streams[i] = slot.req.sample_stream
+                    # a checkpoint resume admits with pre-seeded output, so
+                    # the prefill-completion sample/done mask must start at
+                    # the emission count, not 0 (no-op for fresh requests)
+                    steps[i] = len(slot.req.output)
+                    if slot.req.stop_token is not None:
+                        stops[i] = slot.req.stop_token
+                    max_news[i] = slot.req.max_new_tokens
             self.cache_mgr.push_table()
-            if self.sample_on_device:
-                nxt, done, self.cache_mgr.cache = self._prefill(
-                    self.params, self.cache_mgr.cache, tokens, offsets, lengths,
-                    temps, streams, steps, stops, max_news,
-                )
-                nxt, done, lg = np.asarray(nxt), np.asarray(done), None
-            else:
-                logits, self.cache_mgr.cache = self._prefill(
-                    self.params, self.cache_mgr.cache, tokens, offsets, lengths
-                )
-                nxt, done, lg = None, None, np.asarray(logits)
+            with TraceAnnotation("serve.dispatch.prefill", rows=len(rows), tokens=n_tokens):
+                if self.sample_on_device:
+                    nxt, done, self.cache_mgr.cache = self._prefill(
+                        self.params, self.cache_mgr.cache, tokens, offsets, lengths,
+                        temps, streams, steps, stops, max_news,
+                    )
+                else:
+                    logits, self.cache_mgr.cache = self._prefill(
+                        self.params, self.cache_mgr.cache, tokens, offsets, lengths
+                    )
+            with TraceAnnotation("serve.readback", kind="prefill"):
+                if self.sample_on_device:
+                    nxt, done, lg = np.asarray(nxt), np.asarray(done), None
+                else:
+                    nxt, done, lg = None, None, np.asarray(logits)
             self.stats.prefill_dispatches += 1
             self.stats.dispatches += 1
             self.heartbeat()
-            for i in rows:
-                slot = slots[i]
-                n = min(plan[i], len(slot.remaining_prompt))
-                del slot.remaining_prompt[:n]
-                slot.pos += n
-                self.stats.prompt_tokens_ingested += n
-                if not slot.remaining_prompt:
-                    # prompt fully resident: publish its full pages to the
-                    # prefix cache BEFORE accept (which may finish the row
-                    # and drop its references)
-                    self.cache_mgr.prefix_insert(i, slot.req.prompt)
-                    if self.worker_role == "prefill":
-                        # disaggregated prefill: the full prompt's KV —
-                        # full pages plus the sub-page tail under its
-                        # extended content key — is published while the
-                        # row still holds its pages, then the request
-                        # finishes WITHOUT sampling.  Sampling streams
-                        # are (seed, stream, step)-keyed, so skipping the
-                        # draw consumes no state: the decode worker's
-                        # frontier sample at (stream, 0) is the same
-                        # token a monolith would have emitted here
-                        self.cache_mgr.publish_generation(i, slot.req.prompt)
-                        self.cache_mgr.ensure_chain_published(i, slot.req.prompt)
-                        self.scheduler.finish(i)
-                        continue
-                    # the chunk's last-token logits seed generation
-                    tok = (
-                        int(nxt[i])
-                        if nxt is not None
-                        else self._host_sample(
-                            lg[i], slot.req.temperature,
-                            stream=slot.req.sample_stream,
-                            step=len(slot.req.output),
-                        )
-                    )
-                    self._accept_token(i, tok, bool(done[i]) if done is not None else None)
-                    emitted += 1
+            with TraceAnnotation("serve.accept"):
+                emitted += self._accept_prefill(rows, lengths, nxt, done, lg)
+
+    def _plan_prefill(self, left: Optional[int]) -> Tuple[Dict[int, int], Optional[int]]:
+        """Per-row prompt tokens for the next prefill dispatch under the
+        tick's remaining budget ``left`` (None = unbounded: drain every
+        prompt this tick), fixed BEFORE the reservation pass.  An empty
+        plan ends the tick's ingestion.  Returns ``(plan, left)``."""
+        C = self.prefill_chunk
+        slots = self.scheduler.slots
+        plan: Dict[int, int] = {}
+        prefilling = [
+            i for i, s in enumerate(slots)
+            if s.req is not None and s.remaining_prompt
+        ]
+        if not prefilling or (left is not None and left <= 0):
+            return plan, left
+        if left is None:
+            for i in prefilling:
+                plan[i] = min(C, len(slots[i].remaining_prompt))
+            return plan, left
+        # fair-share the remaining budget across prefilling rows (ceil of
+        # an even split each), rotating the head row by tick so a budget
+        # smaller than the row count cannot pin-starve the same rows
+        # forever — lowest-index-first would hold a short prompt hostage
+        # behind a long one
+        start = self.scheduler.tick % len(prefilling)
+        order = prefilling[start:] + prefilling[:start]
+        for idx, i in enumerate(order):
+            share = -(-left // (len(order) - idx))
+            n = min(C, len(slots[i].remaining_prompt), share)
+            if n > 0:
+                plan[i] = n
+                left -= n
+        return plan, left
+
+    def _accept_prefill(self, rows, lengths, nxt, done, lg) -> int:
+        """Advance each row of a prefill dispatch past its ingested chunk;
+        a row whose prompt is now resident publishes it and takes its
+        first token."""
+        emitted = 0
+        slots = self.scheduler.slots
+        for i in rows:
+            slot = slots[i]
+            n = int(lengths[i])
+            del slot.remaining_prompt[:n]
+            slot.pos += n
+            self.stats.prompt_tokens_ingested += n
+            if slot.remaining_prompt:
+                continue
+            # prompt fully resident: publish its full pages to the prefix
+            # cache BEFORE accept (which may finish the row and drop its
+            # references)
+            self.cache_mgr.prefix_insert(i, slot.req.prompt)
+            if self.worker_role == "prefill":
+                # disaggregated prefill: the full prompt's KV — full pages
+                # plus the sub-page tail under its extended content key —
+                # is published while the row still holds its pages, then
+                # the request finishes WITHOUT sampling.  Sampling streams
+                # are (seed, stream, step)-keyed, so skipping the draw
+                # consumes no state: the decode worker's frontier sample
+                # at (stream, 0) is the same token a monolith would have
+                # emitted here
+                with TraceAnnotation("serve.prefix_insert"):
+                    self.cache_mgr.publish_generation(i, slot.req.prompt)
+                with TraceAnnotation("serve.store.publish"):
+                    self.cache_mgr.ensure_chain_published(i, slot.req.prompt)
+                self.scheduler.finish(i)
+                continue
+            # the chunk's last-token logits seed generation
+            tok = (
+                int(nxt[i])
+                if nxt is not None
+                else self._host_sample(
+                    lg[i], slot.req.temperature,
+                    stream=slot.req.sample_stream,
+                    step=len(slot.req.output),
+                )
+            )
+            self._accept_token(i, tok, bool(done[i]) if done is not None else None)
+            emitted += 1
+        return emitted
 
     # -- decode -------------------------------------------------------------
     def _build_decode_inputs(self):
@@ -659,37 +697,39 @@ class ServeEngine:
             # table, so a shared prefix page in that position must be
             # privatized first — the row itself overwrites the position
             # when its prefill resumes
-            for i, s in enumerate(slots):
-                if s.req is not None:
-                    self.cache_mgr.ensure_pages(i, s.pos + 1, write_start=s.pos)
-        tokens = np.zeros((B, 1), np.int32)
-        pos = np.zeros((B,), np.int32)
-        temps = np.zeros((B,), np.float32)
-        streams = np.zeros((B,), np.int32)
-        steps = np.zeros((B,), np.int32)
-        stops = np.full((B,), -1, np.int32)
-        max_news = np.full((B,), 1 << 30, np.int32)
-        active = []
-        for i, slot in enumerate(slots):
-            # parked rows keep their stale pos: dense mode confines the
-            # write to their own (dead) row, which is zeroed at refill;
-            # paged mode drops it on the OOB page-table sentinel
-            pos[i] = slot.pos
-            if slot.req is None or self._mid_prefill(slot):
-                continue
-            active.append(i)
-            if slot.remaining_prompt:  # decode-path ingestion fallback
-                tokens[i, 0] = slot.remaining_prompt[0]
-            elif slot.req.output:
-                tokens[i, 0] = slot.req.output[-1]
-            else:
-                tokens[i, 0] = slot.req.prompt[-1]
-            temps[i] = slot.req.temperature
-            streams[i] = slot.req.sample_stream
-            steps[i] = len(slot.req.output)
-            if slot.req.stop_token is not None:
-                stops[i] = slot.req.stop_token
-            max_news[i] = slot.req.max_new_tokens
+            with TraceAnnotation("serve.pages"):
+                for i, s in enumerate(slots):
+                    if s.req is not None:
+                        self.cache_mgr.ensure_pages(i, s.pos + 1, write_start=s.pos)
+        with TraceAnnotation("serve.inputs"):
+            tokens = np.zeros((B, 1), np.int32)
+            pos = np.zeros((B,), np.int32)
+            temps = np.zeros((B,), np.float32)
+            streams = np.zeros((B,), np.int32)
+            steps = np.zeros((B,), np.int32)
+            stops = np.full((B,), -1, np.int32)
+            max_news = np.full((B,), 1 << 30, np.int32)
+            active = []
+            for i, slot in enumerate(slots):
+                # parked rows keep their stale pos: dense mode confines the
+                # write to their own (dead) row, which is zeroed at refill;
+                # paged mode drops it on the OOB page-table sentinel
+                pos[i] = slot.pos
+                if slot.req is None or self._mid_prefill(slot):
+                    continue
+                active.append(i)
+                if slot.remaining_prompt:  # decode-path ingestion fallback
+                    tokens[i, 0] = slot.remaining_prompt[0]
+                elif slot.req.output:
+                    tokens[i, 0] = slot.req.output[-1]
+                else:
+                    tokens[i, 0] = slot.req.prompt[-1]
+                temps[i] = slot.req.temperature
+                streams[i] = slot.req.sample_stream
+                steps[i] = len(slot.req.output)
+                if slot.req.stop_token is not None:
+                    stops[i] = slot.req.stop_token
+                max_news[i] = slot.req.max_new_tokens
         return active, tokens, pos, temps, streams, steps, stops, max_news
 
     def _mid_prefill(self, slot: Slot) -> bool:
@@ -700,20 +740,26 @@ class ServeEngine:
         return bool(self._use_prefill and slot.remaining_prompt)
 
     def _decode_dispatch(
-        self, tokens, pos, temps, streams, steps, stops, max_news
+        self, n_rows, tokens, pos, temps, streams, steps, stops, max_news
     ) -> Tuple[Optional[np.ndarray], Optional[np.ndarray], Optional[np.ndarray]]:
+        """One decode dispatch over the whole batch; ``n_rows`` rows
+        consume its tokens."""
         self.cache_mgr.push_table()
-        if self.sample_on_device:
-            nxt, done, self.cache_mgr.cache = self._decode(
-                self.params, self.cache_mgr.cache, tokens, pos, temps, streams,
-                steps, stops, max_news,
-            )
-            out = (np.asarray(nxt), np.asarray(done), None)
-        else:
-            logits, self.cache_mgr.cache = self._decode(
-                self.params, self.cache_mgr.cache, tokens, pos
-            )
-            out = (None, None, np.asarray(logits))
+        with TraceAnnotation("serve.dispatch.decode", rows=n_rows, tokens=n_rows):
+            if self.sample_on_device:
+                nxt, done, self.cache_mgr.cache = self._decode(
+                    self.params, self.cache_mgr.cache, tokens, pos, temps, streams,
+                    steps, stops, max_news,
+                )
+            else:
+                logits, self.cache_mgr.cache = self._decode(
+                    self.params, self.cache_mgr.cache, tokens, pos
+                )
+        with TraceAnnotation("serve.readback", kind="decode"):
+            if self.sample_on_device:
+                out = (np.asarray(nxt), np.asarray(done), None)
+            else:
+                out = (None, None, np.asarray(logits))
         self.stats.decode_dispatches += 1
         self.stats.steps_executed += 1
         self.stats.dispatches += 1
@@ -751,8 +797,9 @@ class ServeEngine:
         active, *inputs = self._build_decode_inputs()
         if not active:
             return 0
-        nxt, done, lg = self._decode_dispatch(*inputs)
-        return self._advance_rows(active, nxt, done, lg)
+        nxt, done, lg = self._decode_dispatch(len(active), *inputs)
+        with TraceAnnotation("serve.accept"):
+            return self._advance_rows(active, nxt, done, lg)
 
     def _decode_tick_spec(self) -> int:
         """Speculative decode tick: propose up to ``spec_k`` draft tokens
@@ -778,14 +825,17 @@ class ServeEngine:
         ]
         if not ready:
             return 0
-        hists = {i: slots[i].req.prompt + slots[i].req.output for i in ready}
-        drafts = self.proposer.propose(ready, hists, self.spec_k)
-        plan: Dict[int, List[int]] = {}
-        for i in ready:
-            # cap drafts so the slot can never advance past the max_len-1
-            # truncation point the non-speculative engine finishes at
-            room = self.max_len - 2 - slots[i].pos
-            plan[i] = list(drafts.get(i, []))[:max(0, room)]
+        with TraceAnnotation("serve.inputs"):
+            hists = {i: slots[i].req.prompt + slots[i].req.output for i in ready}
+            with TraceAnnotation("serve.propose"):
+                drafts = self.proposer.propose(ready, hists, self.spec_k)
+            plan: Dict[int, List[int]] = {}
+            for i in ready:
+                # cap drafts so the slot can never advance past the
+                # max_len-1 truncation point the non-speculative engine
+                # finishes at
+                room = self.max_len - 2 - slots[i].pos
+                plan[i] = list(drafts.get(i, []))[:max(0, room)]
         if self.cache_mode == "paged":
             # reservation pass first (see _build_decode_inputs): the
             # verify dispatch writes pos .. pos+len(drafts) per row, and
@@ -795,73 +845,81 @@ class ServeEngine:
             # and shrink the plan under pool pressure instead of
             # preempting or raising — speculation must never OOM a
             # workload the non-speculative engine serves
-            for i in ready:
-                s = slots[i]
-                if s.req is not None:
-                    got = self.cache_mgr.reserve_speculative(
-                        i, s.pos + 1, s.pos + 1 + len(plan[i]),
-                        write_start=s.pos,
-                    )
-                    if got is not None:
-                        plan[i] = plan[i][:max(0, got - (s.pos + 1))]
+            with TraceAnnotation("serve.pages"):
+                for i in ready:
+                    s = slots[i]
+                    if s.req is not None:
+                        got = self.cache_mgr.reserve_speculative(
+                            i, s.pos + 1, s.pos + 1 + len(plan[i]),
+                            write_start=s.pos,
+                        )
+                        if got is not None:
+                            plan[i] = plan[i][:max(0, got - (s.pos + 1))]
         live = [i for i in ready if slots[i].req is not None]
         if not live:
             return 0
-        tokens = np.zeros((B, T), np.int32)
-        offsets = np.zeros((B,), np.int32)
-        lengths = np.zeros((B,), np.int32)
-        temps = np.zeros((B,), np.float32)
-        streams = np.zeros((B,), np.int32)
-        steps = np.zeros((B,), np.int32)
-        stops = np.full((B,), -1, np.int32)
-        max_news = np.full((B,), 1 << 30, np.int32)
-        for i in live:
-            s = slots[i]
-            d = plan[i]
-            tokens[i, 0] = s.req.output[-1] if s.req.output else s.req.prompt[-1]
-            tokens[i, 1:1 + len(d)] = d
-            offsets[i] = s.pos
-            lengths[i] = 1 + len(d)
-            temps[i] = s.req.temperature
-            streams[i] = s.req.sample_stream
-            steps[i] = len(s.req.output)
-            if s.req.stop_token is not None:
-                stops[i] = s.req.stop_token
-            max_news[i] = s.req.max_new_tokens
-            self.stats.draft_tokens_proposed += len(d)
+        with TraceAnnotation("serve.inputs"):
+            tokens = np.zeros((B, T), np.int32)
+            offsets = np.zeros((B,), np.int32)
+            lengths = np.zeros((B,), np.int32)
+            temps = np.zeros((B,), np.float32)
+            streams = np.zeros((B,), np.int32)
+            steps = np.zeros((B,), np.int32)
+            stops = np.full((B,), -1, np.int32)
+            max_news = np.full((B,), 1 << 30, np.int32)
+            n_tokens = 0
+            for i in live:
+                s = slots[i]
+                d = plan[i]
+                tokens[i, 0] = s.req.output[-1] if s.req.output else s.req.prompt[-1]
+                tokens[i, 1:1 + len(d)] = d
+                offsets[i] = s.pos
+                lengths[i] = 1 + len(d)
+                n_tokens += 1 + len(d)
+                temps[i] = s.req.temperature
+                streams[i] = s.req.sample_stream
+                steps[i] = len(s.req.output)
+                if s.req.stop_token is not None:
+                    stops[i] = s.req.stop_token
+                max_news[i] = s.req.max_new_tokens
+                self.stats.draft_tokens_proposed += len(d)
         self.cache_mgr.push_table()
-        tgt, n_emit, done, self.cache_mgr.cache = self._verify(
-            self.params, self.cache_mgr.cache, tokens, offsets, lengths,
-            temps, streams, steps, stops, max_news,
-        )
-        tgt, n_emit, done = np.asarray(tgt), np.asarray(n_emit), np.asarray(done)
+        with TraceAnnotation("serve.dispatch.verify", rows=len(live), tokens=n_tokens):
+            tgt, n_emit, done, self.cache_mgr.cache = self._verify(
+                self.params, self.cache_mgr.cache, tokens, offsets, lengths,
+                temps, streams, steps, stops, max_news,
+            )
+        with TraceAnnotation("serve.readback", kind="verify"):
+            tgt, n_emit, done = np.asarray(tgt), np.asarray(n_emit), np.asarray(done)
         self.stats.decode_dispatches += 1
         self.stats.steps_executed += 1
         self.stats.dispatches += 1
         self.stats.spec_dispatches += 1
         self.heartbeat()
         emitted = 0
-        for i in live:
-            s = slots[i]
-            n = int(n_emit[i])
-            new_pos = s.pos + n
-            if n < int(lengths[i]):
-                # rejected positions: rewind the write frontier; trailing
-                # whole pages go back to the pool (CoW rollback), stale KV
-                # inside the kept page sits past the frontier (masked)
-                self.cache_mgr.rewind_slot(i, new_pos)
-            s.pos = new_pos
-            self.stats.draft_tokens_accepted += n - 1
-            self.stats.spec_tokens_emitted += n
-            fin = bool(done[i]) or new_pos >= self.max_len - 1
-            for t in range(n):
-                s.req.output.append(int(tgt[i, t]))
-                self.stats.tokens_emitted += 1
-                self.scheduler.on_token(i)
-            emitted += n
-            if fin:
-                self.scheduler.finish(i)
-                self.proposer.release(i)
+        with TraceAnnotation("serve.accept"):
+            for i in live:
+                s = slots[i]
+                n = int(n_emit[i])
+                new_pos = s.pos + n
+                if n < int(lengths[i]):
+                    # rejected positions: rewind the write frontier;
+                    # trailing whole pages go back to the pool (CoW
+                    # rollback), stale KV inside the kept page sits past
+                    # the frontier (masked)
+                    self.cache_mgr.rewind_slot(i, new_pos)
+                s.pos = new_pos
+                self.stats.draft_tokens_accepted += n - 1
+                self.stats.spec_tokens_emitted += n
+                fin = bool(done[i]) or new_pos >= self.max_len - 1
+                for t in range(n):
+                    s.req.output.append(int(tgt[i, t]))
+                    self.stats.tokens_emitted += 1
+                    self.scheduler.on_token(i)
+                emitted += n
+                if fin:
+                    self.scheduler.finish(i)
+                    self.proposer.release(i)
         return emitted
 
     def _decode_tick_grouped(self) -> int:
@@ -878,8 +936,9 @@ class ServeEngine:
             groups.setdefault(self.scheduler.slots[i].pos, []).append(i)
         emitted = 0
         for _, rows in sorted(groups.items()):
-            nxt, done, lg = self._decode_dispatch(*inputs)
-            emitted += self._advance_rows(rows, nxt, done, lg)
+            nxt, done, lg = self._decode_dispatch(len(active), *inputs)
+            with TraceAnnotation("serve.accept"):
+                emitted += self._advance_rows(rows, nxt, done, lg)
         return emitted
 
     # -- bookkeeping ---------------------------------------------------------

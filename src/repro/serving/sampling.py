@@ -25,6 +25,10 @@ straight off that mask — the host never re-derives the stop condition
 from the token stream, and a finished row is parked (and its cache pages
 freed in paged mode) before the next dispatch instead of being filtered
 after the fact.
+
+Each jit target is named by its dispatch kind (``serve_prefill``,
+``serve_decode``, ``serve_verify``), so its program appears in a
+profiler trace's ``XLA Modules`` line as ``jit_serve_decode`` and so on.
 """
 
 from __future__ import annotations
@@ -122,8 +126,8 @@ def make_verify_step(model, base_seed: int) -> Callable:
     Returns ``(tgt (B, T), n_emit (B,), done (B,), cache)``."""
     vocab = model.cfg.vocab_size
 
-    def step(params, cache, tokens, offsets, lengths, temps, streams, steps,
-             stops, max_news):
+    def serve_verify(params, cache, tokens, offsets, lengths, temps, streams,
+                     steps, stops, max_news):
         logits, cache = model.verify_chunk(params, cache, tokens, offsets, lengths)
         B, T = tokens.shape
         t_idx = jnp.arange(T, dtype=jnp.int32)
@@ -149,7 +153,7 @@ def make_verify_step(model, base_seed: int) -> Callable:
         n_emit = jnp.where(lengths > 0, n_emit, 0).astype(jnp.int32)
         return tgt, n_emit, any_done, cache
 
-    return step
+    return serve_verify
 
 
 def make_decode_step(model, base_seed: int, on_device: bool) -> Callable:
@@ -160,20 +164,20 @@ def make_decode_step(model, base_seed: int, on_device: bool) -> Callable:
 
     if not on_device:
 
-        def logits_step(params, cache, tokens, pos):
+        def serve_decode_logits(params, cache, tokens, pos):
             logits, cache = model.decode_step(params, cache, tokens, pos)
             return logits[:, 0, :vocab], cache
 
-        return logits_step
+        return serve_decode_logits
 
-    def step(params, cache, tokens, pos, temps, streams, steps, stops, max_news):
+    def serve_decode(params, cache, tokens, pos, temps, streams, steps, stops, max_news):
         logits, cache = model.decode_step(params, cache, tokens, pos)
         nxt = sample_tokens(
             logits[:, 0, :vocab], temps, streams, steps, base_seed=base_seed
         )
         return nxt, done_mask(nxt, steps, stops, max_news), cache
 
-    return step
+    return serve_decode
 
 
 def make_prefill_step(model, base_seed: int, on_device: bool) -> Callable:
@@ -184,16 +188,16 @@ def make_prefill_step(model, base_seed: int, on_device: bool) -> Callable:
 
     if not on_device:
 
-        def logits_step(params, cache, tokens, offsets, lengths):
+        def serve_prefill_logits(params, cache, tokens, offsets, lengths):
             logits, cache = model.prefill_chunk(params, cache, tokens, offsets, lengths)
             return logits[:, :vocab], cache
 
-        return logits_step
+        return serve_prefill_logits
 
-    def step(params, cache, tokens, offsets, lengths, temps, streams, steps,
-             stops, max_news):
+    def serve_prefill(params, cache, tokens, offsets, lengths, temps, streams, steps,
+                      stops, max_news):
         logits, cache = model.prefill_chunk(params, cache, tokens, offsets, lengths)
         nxt = sample_tokens(logits[:, :vocab], temps, streams, steps, base_seed=base_seed)
         return nxt, done_mask(nxt, steps, stops, max_news), cache
 
-    return step
+    return serve_prefill

@@ -33,6 +33,8 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.serving.types import EngineStats, Request, Slot, percentiles
 
 
@@ -146,9 +148,10 @@ class RequestScheduler:
     # ------------------------------------------------------------ admission
     def begin_tick(self) -> None:
         """Advance the tick clock and run the admission policy."""
-        self.tick += 1
-        self.stats.ticks += 1
-        self.refill()
+        with TraceAnnotation("serve.admit"):
+            self.tick += 1
+            self.stats.ticks += 1
+            self.refill()
 
     def refill(self) -> None:
         if self.refill_policy == "drain" and any(
@@ -165,7 +168,9 @@ class RequestScheduler:
                 # exists — active slots finish and reopen the gate
                 if not self.cache.can_admit():
                     break
-                self._admit(row, self.pending.pop(0))
+                req = self.pending.pop(0)
+                with TraceAnnotation("serve.admit.request", uid=req.uid, row=row):
+                    self._admit(row, req)
 
     def _admit(self, row: int, req: Request) -> None:
         slot = self.slots[row]
