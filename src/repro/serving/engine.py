@@ -29,7 +29,11 @@ hardware, not by dispatch count):
   written in one dispatch instead of token-at-a-time through the decode
   path.  Architectures without fused-prefill support (enc-dec, VLM, MoE
   capacity routing, rolling sliding-window caches) fall back to decode-
-  path ingestion, still at one dispatch per tick.
+  path ingestion, still at one dispatch per tick.  Over a paged cache a
+  prefill dispatch runs only ``prefill_rows`` rows (``PREFILL_SLOTS``):
+  its program gathers the rows that prefill from the slot-indexed inputs
+  and scatters their outputs back, so the host interface keeps its
+  ``(max_batch, ...)`` arrays.
 - **sampling**: greedy/temperature sampling runs on-device inside the
   same dispatch (``repro.serving.sampling``); only ``B`` token ids cross
   the host boundary per tick instead of ``(B, vocab)`` logits.
@@ -87,10 +91,30 @@ __all__ = ["Request", "ServeEngine", "Slot"]
 _Slot = Slot
 
 
+# A fused prefill dispatch on a paged cache runs at most this many prompt
+# slots (rows x prefill_chunk).  On a TPU v5e, at granite-34b widths and 4
+# layers, a dispatch pays a fixed cost (the f32-to-bf16 weight conversions
+# and the weight read) plus matmul time per slot: it took 22.8-23.9 ms at
+# 64-256 slots, 29.1 ms at 512, 40.5 ms at 1,024 and 110.7 ms at 4,096.
+# Up to 512 slots stay within ~1.3x of that floor, and a tick whose
+# 2048-token prefill budget is full of prompt tokens costs four (8 x 64)
+# dispatches, about what the one (64 x 64) dispatch they replace cost.
+PREFILL_SLOTS = 512
+
+
+def prefill_rows(prefill_chunk: int, max_batch: int) -> int:
+    """Rows of a fused prefill dispatch over a paged cache: the largest
+    power of two whose ``prefill_chunk``-token rows fit ``PREFILL_SLOTS``,
+    clamped to ``[1, max_batch]``."""
+    fit = max(1, PREFILL_SLOTS // max(1, prefill_chunk))
+    return min(1 << (fit.bit_length() - 1), max_batch)
+
+
 def _jit_cached(model: Model, key: tuple, builder: Callable) -> Callable:
     """Memoize a jitted dispatch on the *model* instance.
 
-    The jit targets close over (model, seed, sample_on_device) only —
+    The jit targets close over (model, seed, sample_on_device, and the
+    prefill's row count) only —
     params are call arguments — so every engine built on the same model
     with the same key can share one compiled function.  Elastic serving
     rebuilds engines after every lease takeover/revocation; without this
@@ -224,10 +248,19 @@ class ServeEngine:
             and model.supports_fused_prefill
             and not self.cache_mgr.cache_is_rolling()
         )
+        # rows the prefill program runs: over a paged cache only the rows
+        # that prefill are gathered into a smaller (R, C) program; a dense
+        # cache's rows are its cache rows, and SSM state is per row, so
+        # there every dispatch runs all max_batch rows
+        self.prefill_rows = (
+            prefill_rows(self.prefill_chunk, max_batch)
+            if cache_mode == "paged" and self._use_prefill else max_batch
+        )
         self._prefill = (
             _jit_cached(
-                model, ("prefill", rng_seed, sample_on_device),
-                lambda: make_prefill_step(model, rng_seed, sample_on_device),
+                model, ("prefill", rng_seed, sample_on_device, self.prefill_rows),
+                lambda: make_prefill_step(model, rng_seed, sample_on_device,
+                                          self.prefill_rows),
             )
             if self._use_prefill
             else None
@@ -579,7 +612,9 @@ class ServeEngine:
                         stops[i] = slot.req.stop_token
                     max_news[i] = slot.req.max_new_tokens
             self.cache_mgr.push_table()
-            with TraceAnnotation("serve.dispatch.prefill", rows=len(rows), tokens=n_tokens):
+            slots_run = self.prefill_rows * C
+            with TraceAnnotation("serve.dispatch.prefill", rows=len(rows), tokens=n_tokens,
+                                 slots=slots_run):
                 if self.sample_on_device:
                     nxt, done, self.cache_mgr.cache = self._prefill(
                         self.params, self.cache_mgr.cache, tokens, offsets, lengths,
@@ -595,6 +630,7 @@ class ServeEngine:
                 else:
                     nxt, done, lg = None, None, np.asarray(logits)
             self.stats.prefill_dispatches += 1
+            self.stats.prefill_slots += slots_run
             self.stats.dispatches += 1
             self.heartbeat()
             with TraceAnnotation("serve.accept"):
@@ -603,9 +639,11 @@ class ServeEngine:
     def _plan_prefill(self, left: Optional[int]) -> Tuple[Dict[int, int], Optional[int]]:
         """Per-row prompt tokens for the next prefill dispatch under the
         tick's remaining budget ``left`` (None = unbounded: drain every
-        prompt this tick), fixed BEFORE the reservation pass.  An empty
-        plan ends the tick's ingestion.  Returns ``(plan, left)``."""
-        C = self.prefill_chunk
+        prompt this tick), fixed BEFORE the reservation pass.  A plan holds
+        at most ``prefill_rows`` rows; :meth:`_ingest_prompts` dispatches
+        again while budget and prefilling rows remain.  An empty plan ends
+        the tick's ingestion.  Returns ``(plan, left)``."""
+        C, R = self.prefill_chunk, self.prefill_rows
         slots = self.scheduler.slots
         plan: Dict[int, int] = {}
         prefilling = [
@@ -615,16 +653,16 @@ class ServeEngine:
         if not prefilling or (left is not None and left <= 0):
             return plan, left
         if left is None:
-            for i in prefilling:
+            for i in prefilling[:R]:
                 plan[i] = min(C, len(slots[i].remaining_prompt))
             return plan, left
-        # fair-share the remaining budget across prefilling rows (ceil of
+        # fair-share the remaining budget across the plan's rows (ceil of
         # an even split each), rotating the head row by tick so a budget
         # smaller than the row count cannot pin-starve the same rows
         # forever — lowest-index-first would hold a short prompt hostage
         # behind a long one
         start = self.scheduler.tick % len(prefilling)
-        order = prefilling[start:] + prefilling[:start]
+        order = (prefilling[start:] + prefilling[:start])[:R]
         for idx, i in enumerate(order):
             share = -(-left // (len(order) - idx))
             n = min(C, len(slots[i].remaining_prompt), share)
@@ -1011,7 +1049,7 @@ def _cache_alias(name: str) -> property:
 
 for _name in (
     "steps_executed", "decode_dispatches", "prefill_dispatches", "dispatches",
-    "tokens_emitted", "prompt_tokens_ingested",
+    "tokens_emitted", "prompt_tokens_ingested", "prefill_slots",
     "pages_in_use", "peak_pages", "page_allocs", "page_bytes",
     "dense_cache_bytes",
     "prefix_hit_tokens", "prompt_tokens_skipped", "pages_shared_peak",

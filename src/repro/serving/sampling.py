@@ -33,7 +33,7 @@ profiler trace's ``XLA Modules`` line as ``jit_serve_decode`` and so on.
 
 from __future__ import annotations
 
-from typing import Callable
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -180,24 +180,72 @@ def make_decode_step(model, base_seed: int, on_device: bool) -> Callable:
     return serve_decode
 
 
-def make_prefill_step(model, base_seed: int, on_device: bool) -> Callable:
+def _gather_rows(rows: Optional[int], cache, tokens, offsets, lengths, *per_row):
+    """Gather the rows of a slot-indexed ``(B, ...)`` prefill dispatch that
+    carry tokens into ``rows`` compact rows, inside the program.
+
+    Returns ``(idx, cache, tokens, offsets, lengths, *per_row)``: ``idx``
+    (``(rows,)``) holds the slot of each compact row, ``B`` past the last
+    one; the cache's page table is gathered to those rows (the page pools
+    are shared and addressed by physical page, so they pass through whole).
+    A padding row reads slot ``B - 1``'s inputs but has length 0: its
+    writes go to the out-of-bounds page and :func:`_to_slots` drops its
+    outputs.  With ``rows`` None, or not below ``B``, nothing is gathered
+    and ``idx`` is None."""
+    B = tokens.shape[0]
+    if rows is None or rows >= B:
+        return (None, cache, tokens, offsets, lengths, *per_row)
+    (idx,) = jnp.nonzero(lengths > 0, size=rows, fill_value=B)
+
+    def take(a):
+        return jnp.take(a, idx, axis=0, mode="clip")
+
+    cache = dict(cache, page_table=take(cache["page_table"]))
+    lengths = jnp.where(idx < B, take(lengths), 0)
+    return (idx, cache, take(tokens), take(offsets), lengths, *map(take, per_row))
+
+
+def _to_slots(idx, B: int, table, cache, *outs):
+    """Scatter compact-row outputs back to ``(B, ...)`` slots (padding rows
+    dropped, other slots zero) and put the full page table back."""
+    if idx is None:
+        return (*outs, cache)
+    outs = [jnp.zeros((B,) + o.shape[1:], o.dtype).at[idx].set(o, mode="drop") for o in outs]
+    return (*outs, dict(cache, page_table=table))
+
+
+def make_prefill_step(model, base_seed: int, on_device: bool,
+                      rows: Optional[int] = None) -> Callable:
     """Build the engine's fused chunked-prefill jit target (last-token
     logits sampled on-device with the done mask, or returned raw for the
-    host fallback)."""
+    host fallback).
+
+    Inputs and outputs are indexed by slot, ``(B, ...)``.  With ``rows``
+    below ``B`` (a paged cache), the program gathers the at most ``rows``
+    slots with ``lengths > 0`` and runs the model on ``(rows, T)`` tokens
+    only (:func:`_gather_rows`); the caller never plans more rows than
+    that into one dispatch."""
     vocab = model.cfg.vocab_size
 
     if not on_device:
 
         def serve_prefill_logits(params, cache, tokens, offsets, lengths):
+            B, table = tokens.shape[0], cache.get("page_table")
+            idx, cache, tokens, offsets, lengths = _gather_rows(
+                rows, cache, tokens, offsets, lengths)
             logits, cache = model.prefill_chunk(params, cache, tokens, offsets, lengths)
-            return logits[:, :vocab], cache
+            return _to_slots(idx, B, table, cache, logits[:, :vocab])
 
         return serve_prefill_logits
 
     def serve_prefill(params, cache, tokens, offsets, lengths, temps, streams, steps,
                       stops, max_news):
+        B, table = tokens.shape[0], cache.get("page_table")
+        idx, cache, tokens, offsets, lengths, temps, streams, steps, stops, max_news = (
+            _gather_rows(rows, cache, tokens, offsets, lengths,
+                          temps, streams, steps, stops, max_news))
         logits, cache = model.prefill_chunk(params, cache, tokens, offsets, lengths)
         nxt = sample_tokens(logits[:, :vocab], temps, streams, steps, base_seed=base_seed)
-        return nxt, done_mask(nxt, steps, stops, max_news), cache
+        return _to_slots(idx, B, table, cache, nxt, done_mask(nxt, steps, stops, max_news))
 
     return serve_prefill

@@ -98,6 +98,7 @@ class EngineStats:
     steps_executed: int = 0  # jitted decode calls (seed-compatible name)
     decode_dispatches: int = 0
     prefill_dispatches: int = 0
+    prefill_slots: int = 0  # rows x prefill_chunk the prefill programs ran
     dispatches: int = 0
     tokens_emitted: int = 0
     prompt_tokens_ingested: int = 0

@@ -139,3 +139,10 @@ def test_every_span_is_documented(traced):
         assert named(spans, "serve.propose")
     if mode == "store":
         assert named(spans, "serve.store.hydrate") and named(spans, "serve.store.publish")
+
+
+def test_prefill_slots_are_the_rows_the_program_ran(traced):
+    # max_batch 2 at an 8-token chunk: every row runs (prefill_rows 2)
+    spans, _, _, _ = traced
+    prefill = named(spans, "serve.dispatch.prefill")
+    assert prefill and all(s.args["slots"] == 2 * 8 for s in prefill)

@@ -87,7 +87,7 @@ def test_paged_kernel_compiles_mla_absorbed(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("dispatch", ["decode", "prefill", "verify"])
+@pytest.mark.parametrize("dispatch", ["decode", "prefill", "verify", "prefill_rows"])
 def test_full_width_dispatch_compiles_without_pool_copies(one_chip, monkeypatch, dispatch):
     """The serving engine's dispatches at full width, through the compiled
     kernel: nothing copies or transposes a layer's page pool on its way
@@ -110,6 +110,8 @@ def test_full_width_dispatch_compiles_without_pool_copies(one_chip, monkeypatch,
         "decode": (sampling.make_decode_step(model, 0, True), 1, 1),
         "prefill": (sampling.make_prefill_step(model, 0, True), 16, 2),
         "verify": (sampling.make_verify_step(model, 0), 5, 2),
+        # the paged engine's prefill: the rows that prefill gathered in-program
+        "prefill_rows": (sampling.make_prefill_step(model, 0, True, rows=2), 16, 2),
     }[dispatch]
     compiled = jax.jit(step).lower(
         params, cache, _spec(one_chip, (B, T), jnp.int32),
