@@ -5,8 +5,10 @@ The configuration's file is the ``file`` of its ``configs`` entry; the
 mix is ``traffic/<traffic>.json``; each metric is computed by
 ``metrics/<name>.py``'s ``read(run)``, or, where no such file is, by
 that of the name without its last ``.<suffix>`` (``mfu.online`` and
-``mfu.offline`` both read ``metrics/mfu.py``).  A new configuration, mix
-or metric is therefore a new file and a new entry, never an edit here.
+``mfu.offline`` both read ``metrics/mfu.py``).  Everything the harness
+knows of a model family is in the reference file the configuration names
+(:func:`reference_module`).  A new configuration, mix, metric or family
+is therefore a new file and a new entry, never an edit here.
 """
 
 from __future__ import annotations
@@ -91,7 +93,10 @@ def reader(name: str, bench_dir: str = BENCH_DIR) -> Callable:
 
 
 def reference_module(name: str, bench_dir: str = BENCH_DIR):
-    """The plain reference ``references/<name>.py`` a configuration names."""
+    """The family file ``references/<name>.py`` a configuration names: its
+    plain reference (``weight_spec``, ``Reference``), weight rule
+    (``weight_scale``), work counts (``extend_work``, ``decode_work``) and
+    tiny widths (``tiny``)."""
     path = os.path.join(bench_dir, "references", name + ".py")
     spec = importlib.util.spec_from_file_location("chipbench_reference_" + name, path)
     mod = importlib.util.module_from_spec(spec)

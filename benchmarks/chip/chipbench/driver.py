@@ -12,8 +12,9 @@ produced it.  A step's admissions happen at its start, so a request's
 admission time is the start of the step whose tick admitted it.
 
 The engine's two jitted dispatches are wrapped, from outside, to record
-each call's logical work (``flops``) and to put a named span around it
-in the profiler's trace; the program itself is not changed.
+each call's logical work (a ``flops.DispatchWork``, counted by the model
+family's ``extend_work`` and ``decode_work``) and to put a named span
+around it in the profiler's trace; the program itself is not changed.
 """
 
 from __future__ import annotations
@@ -82,9 +83,13 @@ class Window:
 
 
 class Recorder:
-    """Wraps the engine's dispatches and watches its slots after each step."""
+    """Wraps the engine's dispatches and watches its slots after each step.
+    ``extend_work(model, dtype, offsets, lengths, completes)`` and
+    ``decode_work(model, dtype, positions)`` count a dispatch's work: those
+    of the reference file the configuration names."""
 
-    def __init__(self, engine, model: dict, dtype: str):
+    def __init__(self, engine, model: dict, dtype: str, extend_work: Callable,
+                 decode_work: Callable):
         self.engine = engine
         self.t0 = clock()
         self.dispatches: List[DispatchRecord] = []
@@ -94,14 +99,14 @@ class Recorder:
         def traced_prefill(params, cache, tokens, offsets, lengths, *rest):
             done = [int(lengths[i]) > 0 and int(lengths[i]) == len(slots[i].remaining_prompt)
                     for i in range(len(slots))]
-            work = flops.extend_work(model, dtype, offsets, lengths, done)
+            work = extend_work(model, dtype, offsets, lengths, done)
             self.dispatches.append(DispatchRecord(clock() - self.t0, "prefill", work))
             with span("bench.dispatch.prefill"):
                 return prefill(params, cache, tokens, offsets, lengths, *rest)
 
         def traced_decode(params, cache, tokens, pos, *rest):
             rows = [i for i, s in enumerate(slots) if s.req is not None and not s.remaining_prompt]
-            work = flops.decode_work(model, dtype, [int(pos[i]) for i in rows])
+            work = decode_work(model, dtype, [int(pos[i]) for i in rows])
             self.dispatches.append(DispatchRecord(clock() - self.t0, "decode", work))
             with span("bench.dispatch.decode"):
                 return decode(params, cache, tokens, pos, *rest)

@@ -56,14 +56,17 @@ class Run:
 
 
 def build_arch(config: dict):
-    """The program's ArchConfig for a configuration file, checked key by key."""
+    """The program's ArchConfig for a configuration file, checked key by
+    key.  ``family``, and a key the program derives (it has a
+    ``resolved_<key>``), are compared and never set."""
     from repro.configs import get_arch
 
-    model = config["model"]
-    arch = dataclasses.replace(get_arch(config["arch"]), **{
-        k: v for k, v in model.items() if k not in ("family", "head_dim")})
+    model, base = config["model"], get_arch(config["arch"])
+    derived = {k for k in model if hasattr(base, "resolved_" + k)}
+    arch = dataclasses.replace(base, **{
+        k: v for k, v in model.items() if k != "family" and k not in derived})
     for k, v in model.items():
-        got = arch.resolved_head_dim if k == "head_dim" else getattr(arch, k)
+        got = getattr(arch, "resolved_" + k if k in derived else k)
         if got != v:
             raise ValueError(f"{config['name']}: {k} is {got!r} in the program, {v!r} in the file")
     return arch
@@ -113,10 +116,11 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, *, trace: bool, t_star
     jdt = {"float32": jax.numpy.float32, "bfloat16": jax.numpy.bfloat16}[dtype]
     eng_cfg = cell.engine
     model_cfg = config["model"]
+    family = cells.reference_module(config["reference"], cell.bench_dir)
     arch = build_arch(config)
     model = Model(arch, ModelRuntime(dtype=jdt))
     spec = weights.spec_of(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    params = weights.make(spec, model_cfg, seed, jdt)
+    params = weights.make(spec, model_cfg, seed, jdt, family.weight_scale)
     jax.block_until_ready(params)
     engine = ServeEngine(
         model, params, max_batch=eng_cfg["max_batch"], max_len=eng_cfg["max_len"],
@@ -126,7 +130,7 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, *, trace: bool, t_star
     )
     if fault is not None:
         fault(engine)
-    recorder = driver.Recorder(engine, model_cfg, dtype)
+    recorder = driver.Recorder(engine, model_cfg, dtype, family.extend_work, family.decode_work)
 
     def make_request(s):
         return Request(uid=s.uid, prompt=list(s.prompt), max_new_tokens=s.max_new_tokens,
@@ -163,10 +167,9 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, *, trace: bool, t_star
     gc.collect()
     if not check_outputs:
         return run
-    ref_mod = cells.reference_module(config["reference"], cell.bench_dir)
-    ref_w = weights.make({tuple(k): tuple(v) for k, v in ref_mod.weight_spec(model_cfg).items()},
-                         model_cfg, seed, jax.numpy.float32)
-    ref = ref_mod.Reference(model_cfg, ref_w, eng_cfg["max_len"])
+    ref_w = weights.make({tuple(k): tuple(v) for k, v in family.weight_spec(model_cfg).items()},
+                         model_cfg, seed, jax.numpy.float32, family.weight_scale)
+    ref = family.Reference(model_cfg, ref_w, eng_cfg["max_len"])
     run.gap = check.widest_gap(ref, sample) if sample else {"max_logit_gap": float("inf"), "tokens": 0}
     del ref, ref_w
     gc.collect()

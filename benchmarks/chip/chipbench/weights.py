@@ -1,54 +1,27 @@
 """Weights made from the run's seed, by path, on the device.
 
 One function decides every weight: the leaf at path ``p`` with shape
-``s`` is ``normal(fold_in(key(seed), crc32(p)), s) * scale(p)``, or ones
-for a norm's gain.  The program's weights are made in the pytree layout
-the program asks for (``jax.eval_shape`` of its own ``init``), in the type
-they are served in, in one jitted call; the reference makes its own copy
-from its own table of paths and shapes.  Where the program's layout
-differs from the reference's, a leaf gets other numbers and the
-comparison that decides ``correct`` fails.
-
-Scales follow the usual initialisation of such models: embeddings and
-learned positions at 0.02, projections at ``1 / sqrt(fan_in)``, and the
-two projections that write back into the residual stream further by
-``1 / sqrt(2 n_layers)``.  Norm gains are ones; biases (a LayerNorm's,
-the q/k/v projections') are drawn at 0.1, not zeros, so that the
-comparison sees a program that drops one.
+``s`` is ``normal(fold_in(key(seed), crc32(p)), s) * scale``, or ones
+for a norm's gain, where ``scale`` is what the model family's rule gives
+for ``(p, s)``.  The rule is ``weight_scale(path, shape, model)`` of the
+reference file a configuration names (``references/<reference>.py``),
+which is the one place that knows the family's leaves.  The program's
+weights are made in the pytree layout the program asks for
+(``jax.eval_shape`` of its own ``init``), in the type they are served
+in, in one jitted call; the reference makes its own copy from its own
+table of paths and shapes.  Where the program's layout differs from the
+reference's, a leaf gets other numbers and the comparison that decides
+``correct`` fails.
 """
 
 from __future__ import annotations
 
-import math
+import inspect
 import zlib
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
-
-NORMS = ("ln1", "ln2", "ln_f")
-BIAS = 0.1
-
-
-def _scale(path: tuple, shape: tuple, model: dict):
-    """Standard deviation of the leaf at ``path``; ``"ones"`` for a norm's gain."""
-    name, parent = path[-1], (path[-2] if len(path) > 1 else "")
-    if parent in NORMS:
-        return {"w": "ones", "b": BIAS}[name]
-    fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
-    resid = 1.0 / math.sqrt(2 * model["n_layers"])
-    if path in (("embed",), ("pos",)):
-        return 0.02
-    if path == ("lm_head",):
-        return 1.0 / math.sqrt(fan_in)
-    if parent == "attn" and name in ("wq", "wk", "wv"):
-        return 1.0 / math.sqrt(fan_in)
-    if parent == "attn" and name in ("bq", "bk", "bv"):
-        return BIAS
-    if parent in ("attn", "mlp") and name == "wo":
-        return resid / math.sqrt(fan_in)
-    if parent == "mlp" and name in ("wi", "wg"):
-        return 1.0 / math.sqrt(fan_in)
-    raise KeyError(f"no initialisation rule for weight {'/'.join(path)}")
 
 
 def seed_words(seed: int) -> jnp.ndarray:
@@ -57,10 +30,19 @@ def seed_words(seed: int) -> jnp.ndarray:
     return jnp.asarray([seed & 0xFFFFFFFF, (seed >> 32) & 0xFFFFFFFF], jnp.uint32)
 
 
-def make(spec: dict, model: dict, seed: int, dtype=jnp.float32) -> dict:
-    """Nested dict of weights for ``spec`` (``{path tuple: shape}``)."""
+def make(spec: dict, model: dict, seed: int, dtype, scale: Callable) -> dict:
+    """Nested dict of weights for ``spec`` (``{path tuple: shape}``), each
+    leaf drawn at ``scale(path, shape, model)``: a standard deviation,
+    ``"ones"``, or ``None`` for a leaf the rule does not cover, which is
+    an error."""
     items = sorted(spec.items())
-    scales = [_scale(p, tuple(s), model) for p, s in items]
+    scales = []
+    for p, s in items:
+        sc = scale(p, tuple(s), model)
+        if sc is None:
+            raise KeyError(f"no initialisation rule for weight {'/'.join(p)}: "
+                           f"add it to {scale.__name__} in {inspect.getfile(scale)}")
+        scales.append(sc)
 
     @jax.jit
     def build(words):

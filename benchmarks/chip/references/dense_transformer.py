@@ -17,6 +17,17 @@ fits beside the weights.
 Weights are named by path (``weight_spec``) and made by
 ``chipbench.weights`` from the run's seed, the same function that makes
 the program's weights; the reference makes its own copy.
+
+This file is the one home of what the harness knows about the dense
+family.  Besides ``weight_spec`` and ``Reference`` it exports:
+
+- ``weight_scale(path, shape, model)``: the rule ``chipbench.weights.make``
+  draws every leaf by, the program's and the reference's alike;
+- ``extend_work`` and ``decode_work``: the operations and bytes a prefill
+  or a decode dispatch requires (``chipbench.flops.DispatchWork``), which
+  the recorder counts for ``mfu`` and the paged kernel's roofline;
+- ``tiny(model)``: the model keys a CPU test sets to hold a configuration
+  of this family at tiny widths (``TINY``).
 """
 
 from __future__ import annotations
@@ -28,16 +39,27 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench.flops import DTYPE_BYTES, DispatchWork
+
 HIGHEST = jax.lax.Precision.HIGHEST
 Q_BLOCK = 256
 ROW_BLOCK = 256
+NORMS = ("ln1", "ln2", "ln_f")
+BIAS = 0.1
+GATED = ("silu", "gelu_gated")
+# tiny widths for CPU tests; the key/value head count keeps its kind (``tiny``)
+TINY = dict(n_layers=2, d_model=64, n_heads=4, head_dim=16, d_ff=128, vocab_size=2048)
+
+
+def tiny(model: dict) -> dict:
+    """``TINY``, with one shared key/value head kept one (MQA stays MQA)."""
+    return dict(TINY, n_kv_heads=1 if model["n_kv_heads"] == 1 else 4)
 
 
 def weight_spec(model: dict) -> dict:
     """``{path: shape}`` of every weight, layers stacked on a leading axis."""
     L, d, f, v = model["n_layers"], model["d_model"], model["d_ff"], model["vocab_size"]
-    h, hkv = model["n_heads"], model["n_kv_heads"]
-    hd = model.get("head_dim") or d // h
+    h, hkv, hd = model["n_heads"], model["n_kv_heads"], head_dim(model)
     spec = {
         ("embed",): (v, d),
         ("layers", "ln1", "w"): (L, d),
@@ -64,6 +86,95 @@ def weight_spec(model: dict) -> dict:
     if model["max_position_embeddings"]:
         spec[("pos",)] = (model["max_position_embeddings"], d)
     return spec
+
+
+def weight_scale(path: tuple, shape: tuple, model: dict):
+    """Standard deviation of the leaf at ``path``; ``"ones"`` for a norm's
+    gain; ``None`` for a leaf this family does not have.
+
+    Scales follow the usual initialisation of such models: embeddings and
+    learned positions at 0.02, projections at ``1 / sqrt(fan_in)``, and
+    the two projections that write back into the residual stream further
+    by ``1 / sqrt(2 n_layers)``.  Biases (a LayerNorm's, the q/k/v
+    projections') are drawn at 0.1, not zeros, so that the comparison
+    sees a program that drops one."""
+    name, parent = path[-1], (path[-2] if len(path) > 1 else "")
+    if parent in NORMS:
+        return {"w": "ones", "b": BIAS}[name]
+    fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
+    resid = 1.0 / math.sqrt(2 * model["n_layers"])
+    if path in (("embed",), ("pos",)):
+        return 0.02
+    if path == ("lm_head",):
+        return 1.0 / math.sqrt(fan_in)
+    if parent == "attn" and name in ("wq", "wk", "wv"):
+        return 1.0 / math.sqrt(fan_in)
+    if parent == "attn" and name in ("bq", "bk", "bv"):
+        return BIAS
+    if parent in ("attn", "mlp") and name == "wo":
+        return resid / math.sqrt(fan_in)
+    if parent == "mlp" and name in ("wi", "wg"):
+        return 1.0 / math.sqrt(fan_in)
+    return None
+
+
+# Operations and bytes of the serving dispatches, from logical shapes
+# only: sequence lengths, head counts and head width, never how a kernel
+# lays the work out (padding, packed lanes, padded batch rows or chunk
+# columns are not counted), so a layout change cannot inflate a share of
+# a peak.  A multiply-add counts as two operations.
+
+
+def head_dim(model: dict) -> int:
+    return model.get("head_dim") or model["d_model"] // model["n_heads"]
+
+
+def layer_matmul_params(model: dict) -> int:
+    """Weights one token multiplies through in one decoder layer."""
+    d, h, hkv, hd, f = (model["d_model"], model["n_heads"], model["n_kv_heads"],
+                        head_dim(model), model["d_ff"])
+    attn = d * h * hd + 2 * d * hkv * hd + h * hd * d
+    mlp = (3 if model["activation"] in GATED else 2) * d * f
+    return attn + mlp
+
+
+def lm_head_params(model: dict) -> int:
+    return model["d_model"] * model["vocab_size"]
+
+
+def extend_work(model: dict, dtype: str, offsets, lengths, completes) -> DispatchWork:
+    """A chunk-extend (prefill) dispatch: row ``i`` appends ``lengths[i]``
+    tokens at cache positions ``offsets[i] ..``; token ``t`` attends
+    ``offsets[i] + t + 1`` positions.  ``completes[i]`` marks a row whose
+    prompt ends in this chunk: only its last token's logits are used."""
+    off = np.asarray(offsets, np.float64)
+    n = np.asarray(lengths, np.float64)
+    keep = n > 0
+    off, n = off[keep], n[keep]
+    done = np.asarray(completes, bool)[keep]
+    L, h, hkv, hd = model["n_layers"], model["n_heads"], model["n_kv_heads"], head_dim(model)
+    b = DTYPE_BYTES[dtype]
+    ctx_sum = float(np.sum(n * off + n * (n + 1) / 2))  # sum over tokens of attended positions
+    attn_flops = L * 4.0 * h * hd * ctx_sum
+    # K and V of every attended position once per row, the queries read, the outputs written
+    attn_bytes = L * float(np.sum((off + n) * hkv * hd * 2 * b + 2 * n * h * hd * b))
+    model_flops = (2.0 * L * layer_matmul_params(model) * float(n.sum()) + attn_flops
+                   + 2.0 * lm_head_params(model) * float(done.sum()))
+    return DispatchWork(int(n.sum()), model_flops, attn_flops, attn_bytes)
+
+
+def decode_work(model: dict, dtype: str, positions) -> DispatchWork:
+    """A decode dispatch over the rows that decode: a row at cache position
+    ``p`` writes one token there and attends ``p + 1`` positions."""
+    ctx = np.asarray(positions, np.float64) + 1
+    L, h, hkv, hd = model["n_layers"], model["n_heads"], model["n_kv_heads"], head_dim(model)
+    b = DTYPE_BYTES[dtype]
+    rows = len(ctx)
+    attn_flops = L * 4.0 * h * hd * float(ctx.sum())
+    attn_bytes = L * float(np.sum(ctx * hkv * hd * 2 * b + 2 * h * hd * b))
+    model_flops = (rows * 2.0 * (L * layer_matmul_params(model) + lm_head_params(model))
+                   + attn_flops)
+    return DispatchWork(rows, model_flops, attn_flops, attn_bytes)
 
 
 def _norm(x, p, m):
@@ -98,8 +209,7 @@ def _layer(x, layers, i, *, model):
     """One pre-norm decoder block over a whole padded sequence ``x (S, d)``."""
     m = dict(model)
     S = x.shape[0]
-    h, hkv = m["n_heads"], m["n_kv_heads"]
-    hd = m.get("head_dim") or m["d_model"] // h
+    h, hkv, hd = m["n_heads"], m["n_kv_heads"], head_dim(m)
     g = h // hkv
     w = jax.tree.map(lambda a: a[i], layers)
     pos = jnp.arange(S)

@@ -14,17 +14,16 @@ for p in (BENCH, os.path.join(ROOT, "src")):
 
 from chipbench import cells, harness, peaks  # noqa: E402
 
-TINY_MODEL = dict(n_layers=2, d_model=64, n_heads=4, head_dim=16, d_ff=128, vocab_size=2048)
 TINY_ENGINE = dict(max_batch=4, max_len=256, prefill_chunk=32, prefill_token_budget=64,
                    total_pages=64)
 
 
 def shrink(cell: cells.Cell) -> cells.Cell:
-    """The cell at tiny widths and lengths (the key/value head count keeps
-    its kind: one shared head stays one)."""
+    """The cell at tiny widths and lengths: the model keys come from
+    ``tiny(model)`` of the reference file its configuration names."""
     cell = copy.deepcopy(cell)
     m = cell.config["model"]
-    m.update(TINY_MODEL, n_kv_heads=1 if m["n_kv_heads"] == 1 else 4)
+    m.update(cells.reference_module(cell.config["reference"], cell.bench_dir).tiny(m))
     cell.config["engine"].update(TINY_ENGINE)
     mix = cell.mix
     mix.pop("engine", None)

@@ -6,6 +6,7 @@ batch left out of the prefill dispatch, and a token altered where the
 dispatch produces it.  (No cell spans chips, so no exchange between
 chips can be left out.)"""
 
+import json
 import os
 import subprocess
 import sys
@@ -16,7 +17,8 @@ import chipbench_tiny as tiny
 from chipbench import check, harness
 from chipbench.faults import cache_unchanged, half_batch, token_altered
 
-CELLS = ["granite34b-l4-decode-offline"]
+with open(os.path.join(tiny.ROOT, "BENCHMARK.json")) as f:
+    CELLS = [w["name"] for w in json.load(f)["workloads"]]
 
 
 @pytest.mark.parametrize("workload", CELLS)
@@ -73,7 +75,7 @@ def test_no_chip_no_result():
     """On the CPU the command exits non-zero and prints no result."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run([sys.executable, os.path.join(tiny.BENCH, "run.py"), "--workload",
-                        "granite34b-l4-decode-offline", "--seed", "1", "--seconds", "1", "--trace", "0"],
+                        CELLS[0], "--seed", "1", "--seconds", "1", "--trace", "0"],
                        capture_output=True, text=True, env=env, cwd=tiny.ROOT, timeout=300)
     assert p.returncode == 3 and p.stdout.strip() == "", p.stderr
 
@@ -88,7 +90,7 @@ def test_without_the_program_no_result(tmp_path):
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     code = ("import sys, time; sys.path.insert(0, 'benchmarks/chip'); "
             "from chipbench import harness; "
-            "sys.exit(harness.main('granite34b-l4-decode-offline', 1, 1.0, False, time.perf_counter(), "
+            f"sys.exit(harness.main({CELLS[0]!r}, 1, 1.0, False, time.perf_counter(), "
             "require_chip=False))")
     env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
     env["JAX_PLATFORMS"] = "cpu"
