@@ -13,7 +13,8 @@ ssm (mamba2), hybrid (zamba2 = mamba2 + shared attention block), audio
 
 Layer stacks lower as ``jax.lax.scan`` over stacked parameters so the
 512-device dry-run compiles in seconds; ``runtime.unroll_layers`` unrolls
-instead (used by the roofline per-layer probe).
+instead (used by the roofline per-layer probe).  The serving programs'
+loops also unroll where the stack is float32 (``_unrolls_layers``).
 """
 
 from __future__ import annotations
@@ -622,21 +623,25 @@ class Model:
 
     # ---------------------------------------------------------------- scan
     def _maybe_scan(self, body_fn, carry, xs):
-        """lax.scan over stacked layers, or an unrolled Python loop when
-        runtime.unroll_layers (roofline probes need entry-visible costs)."""
-        if not self.rt.unroll_layers:
+        """``lax.scan(body_fn, carry, xs)`` over stacked layers, or the same
+        loop unrolled: when :func:`_unrolls_layers` says so for ``xs``, or
+        always with ``runtime.unroll_layers`` (roofline probes need
+        entry-visible costs).
+
+        Unrolled, each layer's outputs are written at its index into a
+        stacked buffer, as the scan writes its own, so a cache pool is
+        updated slab by slab and never rebuilt by a stack."""
+        if not (self.rt.unroll_layers or _unrolls_layers(xs)):
             return jax.lax.scan(body_fn, carry, xs)
         n = _stack_len(xs)
-        ys = []
+        ys = None
         for i in range(n):
-            x_i = jax.tree.map(lambda a: a[i], xs)
-            carry, y = body_fn(carry, x_i)
-            ys.append(y)
-        if ys and ys[0] is not None:
-            stacked = jax.tree.map(lambda *zs: jnp.stack(zs), *ys)
-        else:
-            stacked = None
-        return carry, stacked
+            carry, y = body_fn(carry, jax.tree.map(lambda a: a[i], xs))
+            if i == 0:
+                ys = jax.tree.map(lambda a: jax.lax.empty((n,) + a.shape, a.dtype), y)
+            ys = jax.tree.map(
+                lambda buf, a: jax.lax.dynamic_update_index_in_dim(buf, a, i, 0), ys, y)
+        return carry, ys
 
     # ----------------------------------------------------------- embeddings
     def _embed(self, params: Params, tokens: jax.Array, offset: int = 0) -> jax.Array:
@@ -1410,6 +1415,23 @@ def _stack_init(keys, init_fn):
 
 def _stack_len(tree) -> int:
     return jax.tree.leaves(tree)[0].shape[0]
+
+
+def _unrolls_layers(xs) -> bool:
+    """Whether :meth:`Model._maybe_scan` unrolls its loop over ``xs``: when
+    every floating leaf of the stack is float32.
+
+    A matmul at default precision rounds an f32 weight to bf16 on the TPU.
+    Inside ``lax.scan`` XLA turns the rounding of one layer's slice into a
+    convert of the whole stacked weight, which does not depend on the loop
+    index and is hoisted out of the loop: every call then writes a bf16
+    copy of the stack before its first matmul.  Unrolled, each slice's
+    rounding fuses into its own matmul; the numbers are the same.  A stack
+    that holds bf16 (a bf16 model, even where its router or SSM state is
+    f32) has no such copy to make and keeps the scan, which keeps a deep
+    program quick to compile."""
+    floats = [a for a in jax.tree.leaves(xs) if jnp.issubdtype(a.dtype, jnp.floating)]
+    return bool(floats) and all(a.dtype == jnp.float32 for a in floats)
 
 
 def _stack_states(state: Params, n: int) -> Params:

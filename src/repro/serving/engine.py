@@ -92,13 +92,15 @@ _Slot = Slot
 
 
 # A fused prefill dispatch on a paged cache runs at most this many prompt
-# slots (rows x prefill_chunk).  On a TPU v5e, at granite-34b widths and 4
-# layers, a dispatch pays a fixed cost (the f32-to-bf16 weight conversions
-# and the weight read) plus matmul time per slot: it took 22.8-23.9 ms at
-# 64-256 slots, 29.1 ms at 512, 40.5 ms at 1,024 and 110.7 ms at 4,096.
-# Up to 512 slots stay within ~1.3x of that floor, and a tick whose
-# 2048-token prefill budget is full of prompt tokens costs four (8 x 64)
-# dispatches, about what the one (64 x 64) dispatch they replace cost.
+# slots (rows x prefill_chunk).  A dispatch pays a fixed cost (the weight
+# read) plus matmul time per slot.  Chosen from a sweep on a TPU v5e at
+# granite-34b widths and 4 layers, made while the prefill program still
+# converted its f32 weight stacks to bf16 on every dispatch: 22.8-23.9 ms
+# at 64-256 slots, 29.1 ms at 512, 40.5 ms at 1,024 and 110.7 ms at 4,096,
+# so up to 512 slots stayed within ~1.3x of that floor, and a tick whose
+# 2048-token prefill budget is full of prompt tokens cost four (8 x 64)
+# dispatches, about what one (64 x 64) dispatch did.  The conversions are
+# gone (``Model._maybe_scan``); the lower floor has not been swept again.
 PREFILL_SLOTS = 512
 
 
