@@ -13,7 +13,7 @@ a submitter thread trickles them in over time while the engine is
 already generating.  Freed rows are refilled mid-flight (continuous
 batching) — watch the queue-wait/TTFT tick percentiles in the printed
 summary; a drain-then-refill loop would stack arrivals behind the whole
-batch (benchmarks/bench_serving.py quantifies the gap).
+batch.
 
     PYTHONPATH=src python examples/serve_batch.py --staggered
 """
@@ -39,10 +39,9 @@ SHARED = {
     "max_len": 64,
     "max_batch": 2,
     # serving perf knobs (docs/serving.md): chunked prefill ingests whole
-    # prompt slices per dispatch; fused mode issues ONE decode dispatch
+    # prompt slices per dispatch; the engine issues ONE decode dispatch
     # per tick for any position mix
     "prefill_chunk": 8,
-    "dispatch_mode": "fused",
     # paged KV cache: memory scales with resident tokens, not
     # max_batch * max_len; RESULTS.json gains peak_cache_bytes.
     # total_pages is omitted, so each worker sizes its pool adaptively
@@ -90,8 +89,7 @@ def main_batched() -> int:
         res = rt.store.get_json(f"serve/batch{i}/RESULTS.json")
         for uid, r in sorted(res["requests"].items()):
             print(f"batch{i} {uid}: prompt={r['prompt']} -> completion={r['completion']}")
-        # same denominator as benchmarks/bench_serving.py: every token that
-        # crossed the device (emitted + ingested) counts
+        # every token that crossed the device (emitted + ingested) counts
         toks = max(1, res["tokens_emitted"] + res["prompt_tokens_ingested"])
         print(
             f"batch{i} dispatches: decode={res['decode_dispatches']} "

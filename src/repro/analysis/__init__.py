@@ -3,7 +3,7 @@
 Nine PRs in, this repro's coordination disciplines (retry-wrapped
 store/queue ops, durable-before-ack ordering, byte-determinism of
 engine ticks, counter plumbing from ``EngineStats`` through
-``snapshot()`` -> RESULTS -> bench -> docs) lived only in reviewers'
+``snapshot()`` -> RESULTS -> docs) lived only in people's
 heads and scattered regression tests — and several past bugs (the PR 5
 self-preemption live-lock, PR 8's unretried store ops, the truncated
 npz blob crash) were exactly violations of those unwritten rules.

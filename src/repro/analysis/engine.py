@@ -173,9 +173,9 @@ class Project:
 
     ``modules`` maps repo-relative path -> :class:`ParsedModule` for every
     lintable file.  ``root`` is the repo root: project rules locate their
-    registries (``docs/serving.md``, ``benchmarks/check_bench.py``,
-    ``tests/``) relative to it and must *skip quietly* when an anchor
-    file is absent (fixture trees are minimal)."""
+    registries (``docs/serving.md``, ``tests/``) relative to it and must
+    *skip quietly* when an anchor file is absent (fixture trees are
+    minimal)."""
 
     def __init__(self, root: str, modules: Dict[str, ParsedModule]):
         self.root = root

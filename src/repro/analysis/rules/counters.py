@@ -2,42 +2,25 @@
 
 Motivating bugs: three times in PRs 6-9 a new ``EngineStats`` counter
 was plumbed into some-but-not-all of its consumers — present in
-``snapshot()`` but missing from the ``docs/serving.md`` counter tables,
-or named in ``benchmarks/check_bench.py``'s schema under a stale name
-after a rename — and the drift was only caught by a reviewer reading
-diffs side by side.  The three registries can never silently diverge
-again:
+``snapshot()`` but missing from the ``docs/serving.md`` counter tables
+— and the drift was only caught by reading diffs side by side.  The
+registries can never silently diverge again:
 
 1. every public ``EngineStats`` field must be covered by
    ``snapshot()`` (the dynamic ``fields(self)`` comprehension covers
    all of them; an explicit-dict rewrite must name each one);
-2. every public field must appear (backticked) in ``docs/serving.md``;
-3. every key ``check_bench.py`` requires of a report must be a real
-   ``EngineStats`` field, a ``snapshot()``-derived key, or declared in
-   ``check_bench.DERIVED_KEYS`` (bench-level derived metrics) — a
-   renamed counter fails here instead of silently passing a schema
-   that no report can satisfy;
-4. (absorbed from the standalone ``check_bench`` CLI) every scenario
-   block in the bench schema must be referenced by a tier-1 smoke
-   assertion in ``tests/test_bench_serving.py``, and a committed
-   ``BENCH_serving.json`` must satisfy the schema.
+2. every public field must appear (backticked) in ``docs/serving.md``.
 """
 
 from __future__ import annotations
 
 import ast
-import importlib.util
-import os
 from typing import List, Optional, Set
 
-from repro.analysis.engine import Finding
 from repro.analysis.rules.common import Rule
 
 TYPES_PATH = "src/repro/serving/types.py"
 DOCS_PATH = "docs/serving.md"
-CHECK_BENCH_PATH = os.path.join("benchmarks", "check_bench.py")
-BENCH_TEST_PATH = os.path.join("tests", "test_bench_serving.py")
-BENCH_REPORT_PATH = "BENCH_serving.json"
 
 
 def _engine_stats_fields(module) -> List[ast.AnnAssign]:
@@ -83,20 +66,10 @@ def _snapshot_names(snap: ast.FunctionDef) -> Set[str]:
     return names
 
 
-def _load_check_bench(root: str):
-    path = os.path.join(root, CHECK_BENCH_PATH)
-    if not os.path.exists(path):
-        return None
-    spec = importlib.util.spec_from_file_location("_dslint_check_bench", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 class CounterRegistryRule(Rule):
     rule_id = "R4"
-    title = ("EngineStats fields, snapshot(), the docs counter tables and "
-             "check_bench's schema must agree (no silent counter drift)")
+    title = ("EngineStats fields, snapshot() and the docs counter tables "
+             "must agree (no silent counter drift)")
 
     def check_project(self, project):
         types_mod = project.module(TYPES_PATH)
@@ -109,8 +82,8 @@ class CounterRegistryRule(Rule):
         snap = _snapshot_func(types_mod)
         if snap is None:
             yield types_mod.finding(
-                "R4", 1, "EngineStats has no snapshot() method — RESULTS/"
-                "bench consumers read it")
+                "R4", 1, "EngineStats has no snapshot() method — RESULTS.json "
+                "consumers read it")
         elif not _snapshot_is_dynamic(snap):
             named = _snapshot_names(snap)
             for f in public:
@@ -119,7 +92,7 @@ class CounterRegistryRule(Rule):
                         "R4", f,
                         f"counter {f.target.id!r} is not covered by "
                         "snapshot() — it would silently vanish from "
-                        "RESULTS.json and the bench report",
+                        "RESULTS.json",
                     )
 
         # 2. docs coverage (backticked mention anywhere in serving.md)
@@ -133,61 +106,4 @@ class CounterRegistryRule(Rule):
                         f"missing from the {DOCS_PATH} counter tables — "
                         "operators cannot interpret an undocumented "
                         "counter",
-                    )
-
-        # 3 + 4. check_bench schema cross-check and (absorbed) the
-        # scenario<->test coverage + committed-report checks
-        try:
-            cb = _load_check_bench(project.root)
-        except Exception as e:  # pragma: no cover - import failure is fatal drift
-            yield types_mod.finding(
-                "R4", 1, f"benchmarks/check_bench.py failed to load: {e}")
-            return
-        if cb is None:
-            return
-        field_names = {f.target.id for f in public}
-        snapshot_derived = {"accepted_per_dispatch", "hydration_ticks"}
-        derived = set(getattr(cb, "DERIVED_KEYS", ()))
-        cb_rel = CHECK_BENCH_PATH.replace(os.sep, "/")
-        for scenario, (_path, _engines, engine_keys, block_derived) in (
-            getattr(cb, "SCENARIOS", {}) or {}
-        ).items():
-            for key in tuple(engine_keys) + tuple(block_derived):
-                if key in field_names or key in snapshot_derived or key in derived:
-                    continue
-                yield Finding(
-                    rule="R4", path=cb_rel, line=1,
-                    message=(
-                        f"scenario {scenario!r} requires key {key!r} which "
-                        "is neither an EngineStats field, a snapshot()-"
-                        "derived key, nor declared in DERIVED_KEYS — a "
-                        "renamed/phantom counter"),
-                    scope="SCENARIOS", anchor=f"{scenario}:{key}",
-                )
-        test_src = project.read_text(BENCH_TEST_PATH.replace(os.sep, "/"))
-        if test_src is not None and hasattr(cb, "check_test_coverage"):
-            for problem in cb.check_test_coverage(test_src):
-                yield Finding(
-                    rule="R4", path=cb_rel, line=1,
-                    message=f"bench coverage: {problem}",
-                    scope="coverage", anchor=problem,
-                )
-        report_text = project.read_text(BENCH_REPORT_PATH)
-        if report_text is not None and hasattr(cb, "check_report"):
-            import json as _json
-            try:
-                report = _json.loads(report_text)
-            except ValueError:
-                report = None
-                yield Finding(
-                    rule="R4", path=BENCH_REPORT_PATH, line=1,
-                    message="committed BENCH_serving.json is not valid JSON",
-                    scope="report", anchor="json",
-                )
-            if report is not None:
-                for problem in cb.check_report(report):
-                    yield Finding(
-                        rule="R4", path=BENCH_REPORT_PATH, line=1,
-                        message=f"bench report schema: {problem}",
-                        scope="report", anchor=problem,
                     )

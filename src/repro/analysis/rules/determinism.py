@@ -1,7 +1,7 @@
 """R3 — byte-determinism of engine-tick paths.
 
 Motivating discipline (PR 1 onward, load-bearing since PR 5/7/8): the
-bench gates and every recovery/chaos drill assert *byte-identical*
+serving tests and every recovery/chaos drill assert *byte-identical*
 outputs across replays, preemption reruns, checkpoint resumes and
 fleet A/B legs.  That only holds because engine ticks are pure
 functions of (seeded streams, admission order): sampling uses
@@ -77,7 +77,7 @@ def _set_names(func: ast.AST) -> Set[str]:
 class DeterminismRule(Rule):
     rule_id = "R3"
     title = ("no wall clock / unseeded RNG / unordered-set iteration on "
-             "engine-tick paths (byte-identical replay is a bench gate)")
+             "engine-tick paths (byte-identical replay is a test gate)")
 
     def check_module(self, module, project):
         if "tick" not in module.roles:

@@ -39,10 +39,8 @@ cross-host prefix store hydrates the KV pages the dead worker already
 published.
 
 Engine knobs accepted from the job dict: ``max_batch``, ``max_len``,
-``prefill_chunk``, ``dispatch_mode``, ``sample_on_device``,
-``cache_mode``, ``page_size``, ``total_pages`` (omitted => adaptive),
-``prefix_cache``, ``prefix_match`` (``token`` = sub-page CoW reuse,
-``page`` = page-aligned only), scheduler knobs ``refill_policy`` and
+``prefill_chunk``, ``cache_mode``, ``page_size``, ``total_pages``
+(omitted => adaptive), ``prefix_cache``, the scheduler knob
 ``prefill_token_budget``, and the cross-host prefix store
 (``prefix_store`` truthy + optional ``prefix_store_namespace``): with
 the store on, completed prompts' KV pages are content-hashed into the
@@ -102,7 +100,7 @@ _LEASE_STATES: Dict[tuple, "_LeaseState"] = {}  # dslint: disable=R5(keys embed 
 
 
 def reset_serve_state() -> None:
-    """Drop all cached lease state.  Tests and benchmarks call this
+    """Drop all cached lease state.  Tests and fleet drills call this
     between independent simulated runs: worker ids repeat across fresh
     runtimes, and a stale warm engine would otherwise let state
     "survive" a simulated crash.  Model/param/jit caches are kept —
@@ -172,7 +170,16 @@ def _build_params(job: Dict, ctx: WorkerContext, model) -> Tuple[object, str]:
     return params, f"seed={seed}"
 
 
+# engine options that no longer exist: a job that still sets one would
+# otherwise be served by the one remaining path without a word
+RETIRED_JOB_KEYS = ("dispatch_mode", "sample_on_device", "refill_policy",
+                    "prefix_match")
+
+
 def _build_engine(job: Dict, ctx: WorkerContext) -> ServeEngine:
+    retired = [k for k in RETIRED_JOB_KEYS if k in job]
+    if retired:
+        raise ValueError(f"job sets retired serving option(s) {retired}; remove them")
     model = _cached_model(job)
     params, param_id = _build_params(job, ctx, model)
     cache_mode = str(job.get("cache_mode", "dense"))
@@ -190,7 +197,6 @@ def _build_engine(job: Dict, ctx: WorkerContext) -> ServeEngine:
         if job.get("total_pages"):
             paged_kwargs["total_pages"] = int(job["total_pages"])
         paged_kwargs["prefix_cache"] = bool(job.get("prefix_cache", True))
-        paged_kwargs["prefix_match"] = str(job.get("prefix_match", "token"))
         if job.get("prefix_store"):
             namespace = str(
                 job.get("prefix_store_namespace")
@@ -230,10 +236,7 @@ def _build_engine(job: Dict, ctx: WorkerContext) -> ServeEngine:
         max_batch=int(job.get("max_batch", 4)),
         max_len=int(job.get("max_len", 128)),
         prefill_chunk=int(job.get("prefill_chunk", 16)),
-        dispatch_mode=str(job.get("dispatch_mode", "fused")),
-        sample_on_device=bool(job.get("sample_on_device", True)),
         cache_mode=cache_mode,
-        refill_policy=str(job.get("refill_policy", "continuous")),
         prefill_token_budget=int(budget) if budget is not None else None,
         worker_role=str(job.get("worker_role", "unified")),
         heartbeat=lambda: ctx.heartbeat(),

@@ -62,23 +62,14 @@ class KVCacheManager:
         page_size: int = 16,
         total_pages: Optional[int] = None,
         prefix_cache: bool = True,
-        prefix_match: str = "token",
         prefix_store: Optional[PrefixStore] = None,
     ):
-        if prefix_match not in ("token", "page"):
-            raise ValueError(
-                f"prefix_match must be token|page, got {prefix_match!r}"
-            )
         self.model = model
         self.max_batch = max_batch
         self.max_len = max_len
         self.stats = stats
         self.cache_mode = cache_mode
         self.page_size = int(page_size)
-        # "token" (default) additionally reuses the longest common token
-        # prefix inside the first divergent page via a CoW copy of the
-        # partially-matched page; "page" restores page-aligned matching
-        self.prefix_match = prefix_match
         self.store = prefix_store if cache_mode == "paged" else None
         # background publish worker (created on first use): the tick loop
         # snapshots page bytes + increments counters synchronously, but
@@ -557,13 +548,12 @@ class KVCacheManager:
         hit re-writes one position inside the last shared page, which
         copy-on-write then privatizes.
 
-        With ``prefix_match="token"`` (the default) matching does not
-        stop at the last whole page: the longest common *token* prefix
-        inside the first divergent page is reused too, by copying the
-        partially-matched page into a slot-private page (the donor's
-        divergent tail is overwritten when prefill resumes from the
-        mid-page offset; until then it sits past the slot's write
-        frontier where the causal mask excludes it).  The copy is
+        Matching does not stop at the last whole page: the longest
+        common *token* prefix inside the first divergent page is reused
+        too, by copying the partially-matched page into a slot-private
+        page (the donor's divergent tail is overwritten when prefill
+        resumes from the mid-page offset; until then it sits past the
+        slot's write frontier where the causal mask excludes it).  The copy is
         best-effort: it only consumes a free page (or one LRU-evictable
         cached page), never preempts — a miss just falls back to the
         page-aligned stitch."""
@@ -571,15 +561,7 @@ class KVCacheManager:
             return
         prompt = slot.req.prompt
 
-        def lookup():
-            # page mode must not even SCAN for a partial sibling: the
-            # scan refreshes its LRU stamp, which would perturb the
-            # page-aligned baseline's eviction order
-            if self.prefix_match == "token":
-                return self.prefix.match_partial(prompt)
-            return self.prefix.match(prompt), None, 0
-
-        path, pnode, plen = lookup()
+        path, pnode, plen = self.prefix.match_partial(prompt)
         if self.store is not None:
             n_chunks = min(len(prompt) // self.page_size, self.pages_per_slot)
             hydrate = self._hydrate_demand if demand else self._hydrate
@@ -588,7 +570,7 @@ class KVCacheManager:
                     hydrated = hydrate(prompt, [n.page for n in path], n_chunks)
                 if hydrated:
                     # now extended locally (possibly exposing a new partial)
-                    path, pnode, plen = lookup()
+                    path, pnode, plen = self.prefix.match_partial(prompt)
         path = path[: self.pages_per_slot]
         matched = len(path) * self.page_size
         eff = min(matched, len(prompt) - 1)
@@ -596,11 +578,7 @@ class KVCacheManager:
         # page, capped by the hold-back (>= 1 token must be re-dispatched)
         # and dropped when the slot's table has no room for the CoW page
         partial = 0
-        if (
-            self.prefix_match == "token"
-            and pnode is not None
-            and len(path) < self.pages_per_slot
-        ):
+        if pnode is not None and len(path) < self.pages_per_slot:
             partial = min(plen, len(prompt) - 1 - matched)
         # extended-key tail hydration: when the full-chunk match leaves a
         # sub-page remainder, another worker may have published exactly
@@ -615,7 +593,6 @@ class KVCacheManager:
         rem = list(prompt[matched:])
         if (
             self.store is not None
-            and self.prefix_match == "token"
             and len(path) < self.pages_per_slot
             and 0 < len(rem) < self.page_size
             and len(rem) - 1 > partial

@@ -5,9 +5,8 @@ The serving engine is split along explicit seams (``docs/serving.md``
 has the architecture diagram):
 
 - :class:`repro.serving.scheduler.RequestScheduler` — admission queue,
-  continuous batching (freed rows refilled every tick, ``drain``
-  baseline policy, per-tick prefill token budget), preemption/requeue
-  policy, queue-wait/TTFT accounting;
+  continuous batching (freed rows refilled every tick, per-tick prefill
+  token budget), preemption/requeue policy, queue-wait/TTFT accounting;
 - :class:`repro.serving.cache_manager.KVCacheManager` — dense or paged
   device cache, refcounted free-list allocator, copy-on-write, adaptive
   pool sizing, radix prefix cache, optional cross-host prefix store;
@@ -21,9 +20,7 @@ hardware, not by dispatch count):
 
 - **decode**: ONE jitted dispatch per tick for any mix of slot positions.
   ``Model.decode_step`` takes a per-row position vector ``[B]``, so rows
-  at different depths advance together; the seed engine's one-dispatch-
-  per-distinct-position loop (up to B sequential device calls per token)
-  is retained only as ``dispatch_mode="grouped"`` for benchmarking.
+  at different depths advance together.
 - **prefill**: prompts are ingested through ``Model.prefill_chunk`` in
   ``prefill_chunk``-token slices — the KV/SSM cache for a whole chunk is
   written in one dispatch instead of token-at-a-time through the decode
@@ -37,8 +34,6 @@ hardware, not by dispatch count):
 - **sampling**: greedy/temperature sampling runs on-device inside the
   same dispatch (``repro.serving.sampling``); only ``B`` token ids cross
   the host boundary per tick instead of ``(B, vocab)`` logits.
-  ``sample_on_device=False`` restores the host path (numerically
-  stable: max-subtracted softmax).
 - **stop tokens**: the fused dispatches return a done mask computed on
   device (``repro.serving.sampling.done_mask``); the host finalizes rows
   straight off the mask, and finished rows are parked (pages freed)
@@ -50,9 +45,9 @@ sizing) is documented on :class:`KVCacheManager`; scheduling behaviour
 
 Dispatch accounting: ``decode_dispatches`` / ``prefill_dispatches`` /
 ``dispatches`` (their sum) and ``tokens_emitted`` /
-``prompt_tokens_ingested`` feed ``benchmarks/bench_serving.py``'s
-dispatches-per-token metric.  ``steps_executed`` keeps its seed meaning
-(number of jitted decode calls).  All counters live in one shared
+``prompt_tokens_ingested`` give dispatches per token.
+``steps_executed`` keeps its seed meaning (number of jitted decode
+calls).  All counters live in one shared
 :class:`repro.serving.types.EngineStats` block (``engine.stats``); the
 flat attribute aliases below (``engine.tokens_emitted`` and friends)
 are kept as the stable public surface.
@@ -115,12 +110,12 @@ def prefill_rows(prefill_chunk: int, max_batch: int) -> int:
 def _jit_cached(model: Model, key: tuple, builder: Callable) -> Callable:
     """Memoize a jitted dispatch on the *model* instance.
 
-    The jit targets close over (model, seed, sample_on_device, and the
-    prefill's row count) only —
-    params are call arguments — so every engine built on the same model
-    with the same key can share one compiled function.  Elastic serving
-    rebuilds engines after every lease takeover/revocation; without this
-    each rebuild would retrace and recompile the same program."""
+    The jit targets close over (model, seed, and the prefill's row
+    count) only — params are call arguments — so every engine built on
+    the same model with the same key can share one compiled function.
+    Elastic serving rebuilds engines after every lease takeover/
+    revocation; without this each rebuild would retrace and recompile
+    the same program."""
     memo = model.__dict__.setdefault("_jit_memo", {})
     if key not in memo:
         memo[key] = jax.jit(builder())
@@ -138,15 +133,11 @@ class ServeEngine:
         rng_seed: int = 0,
         heartbeat: Callable[[], None] = lambda: None,
         prefill_chunk: int = 16,
-        dispatch_mode: str = "fused",
-        sample_on_device: bool = True,
         cache_mode: str = "dense",
         page_size: int = 16,
         total_pages: Optional[int] = None,
         prefix_cache: bool = True,
-        prefix_match: str = "token",
         prefix_store: Optional[PrefixStore] = None,
-        refill_policy: str = "continuous",
         prefill_token_budget: Optional[int] = None,
         worker_role: str = "unified",
         speculative: str = "off",
@@ -154,8 +145,6 @@ class ServeEngine:
         draft_model: Optional[Model] = None,
         draft_params=None,
     ):
-        if dispatch_mode not in ("fused", "grouped"):
-            raise ValueError(f"dispatch_mode must be fused|grouped, got {dispatch_mode!r}")
         if cache_mode not in ("dense", "paged"):
             raise ValueError(f"cache_mode must be dense|paged, got {cache_mode!r}")
         if cache_mode == "paged" and not model.supports_paged_cache:
@@ -185,23 +174,12 @@ class ServeEngine:
                 "(the KV handoff is storage-mediated); it would be "
                 "silently inert here"
             )
-        if dispatch_mode == "grouped" and model.cfg.family in ("ssm", "hybrid"):
-            # per-group re-dispatch re-advances recurrent state every extra
-            # call per tick (KV writes are idempotent, recurrences are not):
-            # grouped output would be silently wrong, so refuse up front
-            raise ValueError(
-                "dispatch_mode='grouped' corrupts recurrent SSM/hybrid state; "
-                "use the fused engine for family "
-                f"{model.cfg.family!r}"
-            )
         self.model = model
         self.params = params
         self.max_batch = max_batch
         self.max_len = max_len
         self.heartbeat = heartbeat
         self.prefill_chunk = int(prefill_chunk)
-        self.dispatch_mode = dispatch_mode
-        self.sample_on_device = sample_on_device
         self.cache_mode = cache_mode
 
         # the three layers share one counter block and are cross-wired at
@@ -217,14 +195,12 @@ class ServeEngine:
             page_size=page_size,
             total_pages=total_pages,
             prefix_cache=prefix_cache,
-            prefix_match=prefix_match,
             prefix_store=prefix_store,
         )
         self.worker_role = worker_role
         self.scheduler = RequestScheduler(
             max_batch,
             self.stats,
-            refill_policy=refill_policy,
             prefill_token_budget=prefill_token_budget,
             role=worker_role,
         )
@@ -238,15 +214,12 @@ class ServeEngine:
             if self.scheduler.slots[row].req is not None else None
         )
 
-        self.rng = np.random.default_rng(rng_seed)
-        self._rng_seed = rng_seed
         self._decode = _jit_cached(
-            model, ("decode", rng_seed, sample_on_device),
-            lambda: make_decode_step(model, rng_seed, sample_on_device),
+            model, ("decode", rng_seed),
+            lambda: make_decode_step(model, rng_seed),
         )
         self._use_prefill = (
-            dispatch_mode == "fused"
-            and self.prefill_chunk > 0
+            self.prefill_chunk > 0
             and model.supports_fused_prefill
             and not self.cache_mgr.cache_is_rolling()
         )
@@ -260,9 +233,8 @@ class ServeEngine:
         )
         self._prefill = (
             _jit_cached(
-                model, ("prefill", rng_seed, sample_on_device, self.prefill_rows),
-                lambda: make_prefill_step(model, rng_seed, sample_on_device,
-                                          self.prefill_rows),
+                model, ("prefill", rng_seed, self.prefill_rows),
+                lambda: make_prefill_step(model, rng_seed, self.prefill_rows),
             )
             if self._use_prefill
             else None
@@ -273,8 +245,7 @@ class ServeEngine:
             # every admitted request would sit in its slot forever
             raise ValueError(
                 "worker_role='prefill' requires the fused chunked-prefill "
-                "path (dispatch_mode='fused', prefill_chunk > 0, a "
-                "prefill-capable non-rolling arch)"
+                "path (prefill_chunk > 0, a prefill-capable non-rolling arch)"
             )
         if worker_role == "prefill" and speculative != "off":
             raise ValueError(
@@ -297,12 +268,6 @@ class ServeEngine:
         if speculative != "off":
             if self.spec_k < 1:
                 raise ValueError(f"spec_k must be >= 1, got {spec_k}")
-            if not sample_on_device:
-                raise ValueError(
-                    "speculative decoding verifies and accepts on device; "
-                    "sample_on_device=False would make the verify dispatch "
-                    "round-trip (B, k+1, vocab) logits — unsupported"
-                )
             if model.cfg.family in ("ssm", "hybrid"):
                 # KV rollback is dropping pages past the frontier; a
                 # recurrent state advanced by rejected tokens cannot be
@@ -315,9 +280,9 @@ class ServeEngine:
             if not self._use_prefill:
                 raise ValueError(
                     "speculative decoding verifies k+1 positions through the "
-                    "fused chunk-extend path (dispatch_mode='fused', "
-                    "prefill_chunk > 0, fused-prefill-capable arch, "
-                    "non-rolling cache); it cannot run here"
+                    "fused chunk-extend path (prefill_chunk > 0, fused-"
+                    "prefill-capable arch, non-rolling cache); it cannot "
+                    "run here"
                 )
             self._verify = _jit_cached(
                 model, ("verify", rng_seed),
@@ -353,14 +318,14 @@ class ServeEngine:
             if not self._use_prefill:
                 raise ValueError(
                     "prefill_token_budget requires the fused prefill path "
-                    "(dispatch_mode='fused', prefill_chunk > 0, fused-"
-                    "prefill-capable arch); it would be silently inert here"
+                    "(prefill_chunk > 0, fused-prefill-capable arch); it "
+                    "would be silently inert here"
                 )
 
     # ---------------------------------------------- layer-delegation surface
     # The flat attribute API predates the layer split and is the stable
     # public surface (tests, benchmarks, payloads); everything below is
-    # a view onto the layers, writable where the benchmark re-baselines.
+    # a view onto the layers.
     @property
     def cache(self):
         return self.cache_mgr.cache
@@ -514,18 +479,11 @@ class ServeEngine:
         """One engine tick.
 
         The scheduler admits queued requests into freed rows (continuous
-        batching), then: fused mode ingests pending prompt chunks
+        batching), then the engine ingests pending prompt chunks
         (>= chunk-size tokens per prefill dispatch, bounded by the
         scheduler's per-tick prefill token budget) and advances every
         decode-ready slot one token in a SINGLE decode dispatch
-        regardless of position mix.  Grouped mode reproduces the seed's
-        per-position-group dispatching (with its cross-row KV corruption
-        fixed) for comparison.  NOTE: grouped dispatching is inherently
-        wrong for recurrent (SSM / hybrid) state — every extra per-tick
-        dispatch re-advances all rows' recurrences (KV writes are
-        idempotent, recurrences are not).  That unfixable property is
-        part of why the fused path exists; use grouped mode only on
-        attention-family models.
+        regardless of position mix.
         """
         with TraceAnnotation("serve.step", tick=self.scheduler.tick + 1):
             self.scheduler.begin_tick()
@@ -541,9 +499,7 @@ class ServeEngine:
                 if os.environ.get("DS_DEBUG_INVARIANTS") == "1":
                     self.cache_mgr.check_invariants()
                 return emitted
-            if self.dispatch_mode == "grouped":
-                emitted += self._decode_tick_grouped()
-            elif self.speculative != "off":
+            if self.speculative != "off":
                 emitted += self._decode_tick_spec()
             else:
                 emitted += self._decode_tick_fused()
@@ -617,26 +573,18 @@ class ServeEngine:
             slots_run = self.prefill_rows * C
             with TraceAnnotation("serve.dispatch.prefill", rows=len(rows), tokens=n_tokens,
                                  slots=slots_run):
-                if self.sample_on_device:
-                    nxt, done, self.cache_mgr.cache = self._prefill(
-                        self.params, self.cache_mgr.cache, tokens, offsets, lengths,
-                        temps, streams, steps, stops, max_news,
-                    )
-                else:
-                    logits, self.cache_mgr.cache = self._prefill(
-                        self.params, self.cache_mgr.cache, tokens, offsets, lengths
-                    )
+                nxt, done, self.cache_mgr.cache = self._prefill(
+                    self.params, self.cache_mgr.cache, tokens, offsets, lengths,
+                    temps, streams, steps, stops, max_news,
+                )
             with TraceAnnotation("serve.readback", kind="prefill"):
-                if self.sample_on_device:
-                    nxt, done, lg = np.asarray(nxt), np.asarray(done), None
-                else:
-                    nxt, done, lg = None, None, np.asarray(logits)
+                nxt, done = np.asarray(nxt), np.asarray(done)
             self.stats.prefill_dispatches += 1
             self.stats.prefill_slots += slots_run
             self.stats.dispatches += 1
             self.heartbeat()
             with TraceAnnotation("serve.accept"):
-                emitted += self._accept_prefill(rows, lengths, nxt, done, lg)
+                emitted += self._accept_prefill(rows, lengths, nxt, done)
 
     def _plan_prefill(self, left: Optional[int]) -> Tuple[Dict[int, int], Optional[int]]:
         """Per-row prompt tokens for the next prefill dispatch under the
@@ -673,7 +621,7 @@ class ServeEngine:
                 left -= n
         return plan, left
 
-    def _accept_prefill(self, rows, lengths, nxt, done, lg) -> int:
+    def _accept_prefill(self, rows, lengths, nxt, done) -> int:
         """Advance each row of a prefill dispatch past its ingested chunk;
         a row whose prompt is now resident publishes it and takes its
         first token."""
@@ -707,16 +655,7 @@ class ServeEngine:
                 self.scheduler.finish(i)
                 continue
             # the chunk's last-token logits seed generation
-            tok = (
-                int(nxt[i])
-                if nxt is not None
-                else self._host_sample(
-                    lg[i], slot.req.temperature,
-                    stream=slot.req.sample_stream,
-                    step=len(slot.req.output),
-                )
-            )
-            self._accept_token(i, tok, bool(done[i]) if done is not None else None)
+            self._accept_token(i, int(nxt[i]), bool(done[i]))
             emitted += 1
         return emitted
 
@@ -781,32 +720,24 @@ class ServeEngine:
 
     def _decode_dispatch(
         self, n_rows, tokens, pos, temps, streams, steps, stops, max_news
-    ) -> Tuple[Optional[np.ndarray], Optional[np.ndarray], Optional[np.ndarray]]:
+    ) -> Tuple[np.ndarray, np.ndarray]:
         """One decode dispatch over the whole batch; ``n_rows`` rows
         consume its tokens."""
         self.cache_mgr.push_table()
         with TraceAnnotation("serve.dispatch.decode", rows=n_rows, tokens=n_rows):
-            if self.sample_on_device:
-                nxt, done, self.cache_mgr.cache = self._decode(
-                    self.params, self.cache_mgr.cache, tokens, pos, temps, streams,
-                    steps, stops, max_news,
-                )
-            else:
-                logits, self.cache_mgr.cache = self._decode(
-                    self.params, self.cache_mgr.cache, tokens, pos
-                )
+            nxt, done, self.cache_mgr.cache = self._decode(
+                self.params, self.cache_mgr.cache, tokens, pos, temps, streams,
+                steps, stops, max_news,
+            )
         with TraceAnnotation("serve.readback", kind="decode"):
-            if self.sample_on_device:
-                out = (np.asarray(nxt), np.asarray(done), None)
-            else:
-                out = (None, None, np.asarray(logits))
+            out = (np.asarray(nxt), np.asarray(done))
         self.stats.decode_dispatches += 1
         self.stats.steps_executed += 1
         self.stats.dispatches += 1
         self.heartbeat()
         return out
 
-    def _advance_rows(self, rows, nxt, done, lg) -> int:
+    def _advance_rows(self, rows, nxt, done) -> int:
         emitted = 0
         slots = self.scheduler.slots
         for i in rows:
@@ -821,15 +752,7 @@ class ServeEngine:
                 # publish the prompt's full pages (MoE/MLA archs reach the
                 # prefix cache through this path)
                 self.cache_mgr.prefix_insert(i, slot.req.prompt)
-            tok = (
-                int(nxt[i])
-                if nxt is not None
-                else self._host_sample(
-                    lg[i], slot.req.temperature,
-                    stream=slot.req.sample_stream, step=len(slot.req.output),
-                )
-            )
-            self._accept_token(i, tok, bool(done[i]) if done is not None else None)
+            self._accept_token(i, int(nxt[i]), bool(done[i]))
             emitted += 1
         return emitted
 
@@ -837,9 +760,9 @@ class ServeEngine:
         active, *inputs = self._build_decode_inputs()
         if not active:
             return 0
-        nxt, done, lg = self._decode_dispatch(len(active), *inputs)
+        nxt, done = self._decode_dispatch(len(active), *inputs)
         with TraceAnnotation("serve.accept"):
-            return self._advance_rows(active, nxt, done, lg)
+            return self._advance_rows(active, nxt, done)
 
     def _decode_tick_spec(self) -> int:
         """Speculative decode tick: propose up to ``spec_k`` draft tokens
@@ -962,67 +885,14 @@ class ServeEngine:
                     self.proposer.release(i)
         return emitted
 
-    def _decode_tick_grouped(self) -> int:
-        """Seed-style dispatching: one jitted call per distinct slot
-        position.  Every call carries the full per-row position vector, so
-        cache writes are correct and idempotent across the tick's calls
-        (the seed's scalar-pos variant overwrote OTHER rows' histories);
-        only the group's rows consume their call's outputs."""
-        active, *inputs = self._build_decode_inputs()
-        if not active:
-            return 0
-        groups: Dict[int, List[int]] = {}
-        for i in active:
-            groups.setdefault(self.scheduler.slots[i].pos, []).append(i)
-        emitted = 0
-        for _, rows in sorted(groups.items()):
-            nxt, done, lg = self._decode_dispatch(len(active), *inputs)
-            with TraceAnnotation("serve.accept"):
-                emitted += self._advance_rows(rows, nxt, done, lg)
-        return emitted
-
     # -- bookkeeping ---------------------------------------------------------
-    def _accept_token(self, row: int, tok: int, done: Optional[bool] = None) -> None:
+    def _accept_token(self, row: int, tok: int, done: bool) -> None:
         slot = self.scheduler.slots[row]
         slot.req.output.append(tok)
         self.stats.tokens_emitted += 1
         self.scheduler.on_token(row)
-        if done is None:
-            # host fallback (sample_on_device=False): re-derive the mask
-            done = len(slot.req.output) >= slot.req.max_new_tokens or (
-                slot.req.stop_token is not None and tok == slot.req.stop_token
-            )
         if done or slot.pos >= self.max_len - 1:
             self.scheduler.finish(row)
-
-    def _host_sample(
-        self,
-        lg_row: np.ndarray,
-        temperature: float,
-        stream: Optional[int] = None,
-        step: Optional[int] = None,
-    ) -> int:
-        """Host fallback sampler (``sample_on_device=False``): greedy or
-        max-subtracted softmax — ``np.exp(lg / T)`` on raw logits overflows
-        for large-magnitude logits.
-
-        When the caller passes the request's ``(stream, step)``, the draw
-        comes from an rng keyed on ``(seed, stream, step)`` — like the
-        on-device path, independent of scheduling, slot assignment, and
-        preemption replays.  Without them (direct/debug calls) it falls
-        back to the engine-level rng."""
-        lg = np.asarray(lg_row, np.float64)
-        if temperature <= 0:
-            return int(np.argmax(lg))
-        z = (lg - lg.max()) / temperature
-        p = np.exp(z)
-        p /= p.sum()
-        rng = (
-            np.random.default_rng((self._rng_seed, stream, step))
-            if stream is not None
-            else self.rng
-        )
-        return int(rng.choice(len(p), p=p))
 
     def run_to_completion(self, max_steps: int = 100_000) -> List[Request]:
         steps = 0

@@ -9,9 +9,9 @@ Determinism contract: each request owns a sampling *stream* (an integer
 assigned at submit time) and each emitted token an integer *step* (the
 number of tokens already generated for that request).  The per-token key
 is ``fold_in(fold_in(PRNGKey(base_seed), stream), step)`` — independent
-of batch placement, slot assignment, and dispatch scheduling, so the
-fused single-dispatch engine and the legacy per-position-group engine
-draw token-for-token identical samples.
+of batch placement, slot assignment, and dispatch scheduling, so a
+request draws token-for-token identical samples however it is batched,
+preempted or replayed.
 
 Temperature sampling uses the Gumbel-max trick on max-subtracted logits:
 ``argmax((logits - max(logits)) / T + gumbel)`` is an exact draw from
@@ -156,19 +156,10 @@ def make_verify_step(model, base_seed: int) -> Callable:
     return serve_verify
 
 
-def make_decode_step(model, base_seed: int, on_device: bool) -> Callable:
+def make_decode_step(model, base_seed: int) -> Callable:
     """Build the engine's jit target: vectorized-position decode, with
-    sampling + stop-token done mask fused on-device (default) or raw
-    logits returned for the host-sampling fallback."""
+    sampling + stop-token done mask fused on-device."""
     vocab = model.cfg.vocab_size
-
-    if not on_device:
-
-        def serve_decode_logits(params, cache, tokens, pos):
-            logits, cache = model.decode_step(params, cache, tokens, pos)
-            return logits[:, 0, :vocab], cache
-
-        return serve_decode_logits
 
     def serve_decode(params, cache, tokens, pos, temps, streams, steps, stops, max_news):
         logits, cache = model.decode_step(params, cache, tokens, pos)
@@ -214,11 +205,9 @@ def _to_slots(idx, B: int, table, cache, *outs):
     return (*outs, dict(cache, page_table=table))
 
 
-def make_prefill_step(model, base_seed: int, on_device: bool,
-                      rows: Optional[int] = None) -> Callable:
+def make_prefill_step(model, base_seed: int, rows: Optional[int] = None) -> Callable:
     """Build the engine's fused chunked-prefill jit target (last-token
-    logits sampled on-device with the done mask, or returned raw for the
-    host fallback).
+    logits sampled on-device with the done mask).
 
     Inputs and outputs are indexed by slot, ``(B, ...)``.  With ``rows``
     below ``B`` (a paged cache), the program gathers the at most ``rows``
@@ -226,17 +215,6 @@ def make_prefill_step(model, base_seed: int, on_device: bool,
     only (:func:`_gather_rows`); the caller never plans more rows than
     that into one dispatch."""
     vocab = model.cfg.vocab_size
-
-    if not on_device:
-
-        def serve_prefill_logits(params, cache, tokens, offsets, lengths):
-            B, table = tokens.shape[0], cache.get("page_table")
-            idx, cache, tokens, offsets, lengths = _gather_rows(
-                rows, cache, tokens, offsets, lengths)
-            logits, cache = model.prefill_chunk(params, cache, tokens, offsets, lengths)
-            return _to_slots(idx, B, table, cache, logits[:, :vocab])
-
-        return serve_prefill_logits
 
     def serve_prefill(params, cache, tokens, offsets, lengths, temps, streams, steps,
                       stops, max_news):

@@ -7,19 +7,11 @@ to under pool pressure, and per-request latency accounting (queue wait
 and time-to-first-token, denominated in engine ticks so the numbers are
 deterministic under the virtual clock).
 
-Knobs:
-
-- ``refill_policy``: ``"continuous"`` (default) admits into every freed
-  row at each tick — the continuous-batching behaviour; ``"drain"``
-  only admits when *all* slots are empty (the naive serve-a-batch,
-  drain, serve-the-next-batch loop) and exists as the baseline the
-  benchmark's staggered-arrival scenario compares against.
-- ``prefill_token_budget``: cap on prompt tokens ingested per tick.
-  ``None`` (default) drains every pending prompt chunk before decoding
-  — the historical schedule, kept exactly so the benchmark's
-  dispatch-parity gates hold.  A finite budget interleaves chunked
-  prefill with decode: long cold prompts stop starving the tick's
-  decode dispatch, at the cost of extra prefill dispatches.
+Knob: ``prefill_token_budget`` caps the prompt tokens ingested per
+tick.  ``None`` (default) drains every pending prompt chunk before
+decoding.  A finite budget interleaves chunked prefill with decode: long
+cold prompts stop starving the tick's decode dispatch, at the cost of
+extra prefill dispatches.
 
 The scheduler never touches device state.  Admission calls into the
 :class:`~repro.serving.cache_manager.KVCacheManager` (row reset +
@@ -50,14 +42,9 @@ class RequestScheduler:
         max_batch: int,
         stats: EngineStats,
         *,
-        refill_policy: str = "continuous",
         prefill_token_budget: Optional[int] = None,
         role: str = "unified",
     ):
-        if refill_policy not in ("continuous", "drain"):
-            raise ValueError(
-                f"refill_policy must be continuous|drain, got {refill_policy!r}"
-            )
         if prefill_token_budget is not None and prefill_token_budget <= 0:
             raise ValueError("prefill_token_budget must be positive or None")
         if role not in ("unified", "prefill", "decode"):
@@ -66,7 +53,6 @@ class RequestScheduler:
             )
         self.max_batch = max_batch
         self.stats = stats
-        self.refill_policy = refill_policy
         self.prefill_token_budget = prefill_token_budget
         # disaggregated-serving role: a "decode" scheduler admits ONLY
         # sealed handoff records (fresh prefill work is refused at
@@ -154,10 +140,6 @@ class RequestScheduler:
             self.refill()
 
     def refill(self) -> None:
-        if self.refill_policy == "drain" and any(
-            s.req is not None for s in self.slots
-        ):
-            return
         for row, slot in enumerate(self.slots):
             if slot.req is None and self.pending:
                 # admission control: a request admitted into a pool with

@@ -40,7 +40,7 @@ class Request:
     output: List[int] = field(default_factory=list)
     done: bool = False
     # per-request sampling stream id (assigned at submit; scheduling- and
-    # slot-independent so fused and grouped modes draw identical samples)
+    # slot-independent, so preemption replays draw identical samples)
     sample_stream: int = field(default=0, compare=False, repr=False)
     # checkpoint-resume bookkeeping: number of leading output tokens that
     # were pre-seeded from a generation checkpoint (they are ALSO the

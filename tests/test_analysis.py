@@ -188,46 +188,6 @@ def test_r4_passes_agreeing_registries(tmp_path):
     assert report.ok, report.render()
 
 
-def test_r4_trips_on_phantom_bench_schema_key(tmp_path):
-    check_bench = (
-        "DERIVED_KEYS = frozenset()\n"
-        'SCENARIOS = {"s": (("engines",), ("e",), ("phantom_counter",), ())}\n'
-    )
-    report = lint_tree(tmp_path, {
-        "src/repro/serving/types.py": TYPES_DYNAMIC,
-        "docs/serving.md": DOCS_BOTH,
-        "benchmarks/check_bench.py": check_bench,
-    })
-    r4 = [f for f in report.findings if f.rule == "R4"]
-    assert len(r4) == 1 and "phantom_counter" in r4[0].message, report.render()
-
-
-def test_real_bench_schema_keys_all_classified():
-    """Direct form of the R4 invariant against the real repo: every key
-    check_bench requires is an EngineStats field, a snapshot()-derived
-    key, or a declared DERIVED_KEYS member."""
-    import dataclasses
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "cb_r4", os.path.join(REPO_ROOT, "benchmarks", "check_bench.py")
-    )
-    cb = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(cb)
-    from repro.serving.types import EngineStats
-
-    fields = {
-        f.name for f in dataclasses.fields(EngineStats)
-        if not f.name.startswith("_")
-    }
-    allowed = fields | {"accepted_per_dispatch", "hydration_ticks"} | set(
-        cb.DERIVED_KEYS
-    )
-    for name, (_p, _e, engine_keys, derived) in cb.SCENARIOS.items():
-        unclassified = (set(engine_keys) | set(derived)) - allowed
-        assert not unclassified, f"scenario {name}: {sorted(unclassified)}"
-
-
 # --------------------------------------------------------- R6 (project rule)
 OPS_FIXTURE = "def myop(x):\n    return x\n\n\ndef _helper(x):\n    return x\n"
 

@@ -28,6 +28,8 @@ from repro.serving.prefix_store import PrefixStore
 from repro.serving.scheduler import RequestScheduler
 from repro.serving.types import EngineStats
 
+import fleet_drills
+
 JOB = {"arch": "ds-paper-100m", "arch_overrides": "reduced"}
 PAGE = 8
 
@@ -326,3 +328,40 @@ def test_sweep_mid_handoff_pins_protect_then_fallback_is_byte_identical(
     assert dec2.run_to_completion()[0].output == want
     assert dec2.stats.handoff_fallbacks == 1
     assert dec2.stats.prefix_store_pages_hydrated == 0
+
+
+# --------------------------------------------------------- disaggregation drill
+@pytest.fixture(scope="module")
+def disagg(tmp_path_factory):
+    """A monolithic and a prefill/decode-split fleet at equal hardware."""
+    return fleet_drills.disagg_drill(str(tmp_path_factory.mktemp("disagg")))
+
+
+def test_disagg_drill_lossless_every_request_handed_off(disagg):
+    for leg in ("monolith", "split"):
+        r = disagg[leg]
+        assert r["lost_requests"] == 0, leg
+        assert r["dead_letters"] == 0, leg
+        assert r["byte_identical"] is True, leg
+    split, n = disagg["split"], disagg["n_requests"]
+    # every request travels the storage-mediated handoff path once, with
+    # no replay fallbacks and no seal rejects on a healthy store
+    assert split["handoffs_published"] == split["handoffs_admitted"] == n
+    assert split["handoff_fallbacks"] == 0
+    assert split["handoff_seal_rejects"] == 0
+    assert disagg["monolith"]["handoffs_published"] == 0
+    # the prefill pool never decodes; the decode pool hydrates its KV
+    assert split["roles"]["prefill"]["tokens_emitted"] == 0
+    assert split["roles"]["prefill"]["decode_dispatches"] == 0
+    assert split["prefix_store_pages_hydrated"] > 0
+    assert split["hydration_fetch_ops"] > 0
+    assert split["prefix_store_bytes_fetched"] > 0
+
+
+def test_disagg_drill_split_beats_monolith(disagg):
+    """Decode-side p99 TTFT (engine ticks) and tokens per engine tick
+    strictly beat the monolith, whose chunked prefill interleaves into
+    the same ticks."""
+    split, mono = disagg["split"], disagg["monolith"]
+    assert split["ttft_ticks_p99"] < mono["ttft_ticks_p99"]
+    assert split["tokens_per_tick"] > mono["tokens_per_tick"]

@@ -10,6 +10,7 @@ os.environ.setdefault("DS_DEBUG_INVARIANTS", "1")
 
 import jax  # noqa: F401  (initialize the platform before model builds)
 import numpy as np
+import pytest
 
 import repro.launch.serve  # noqa: F401  (registers distributed-serve)
 import repro.launch.train  # noqa: F401
@@ -32,6 +33,8 @@ from repro.launch.serve import reset_serve_state
 from repro.launch.train import build_model
 from repro.serving.engine import Request, ServeEngine
 from repro.serving.prefix_store import PrefixStore
+
+import fleet_drills
 
 TICK = 30.0
 
@@ -470,3 +473,30 @@ def test_stable_key_is_content_addressed_across_redeliveries():
     m3 = Message(id="uuid-c", body={"uid": "q1", "prompt": [9]},
                  receipt="r3", receive_count=1)
     assert _stable_key(m1) != _stable_key(m3)
+
+
+# ------------------------------------------------------ elastic churn drill
+@pytest.fixture(scope="module")
+def churn(tmp_path_factory):
+    """A static and an autoscaled fleet, each through the same arrival
+    spike and the same two seeded mid-spike spot revocations."""
+    return fleet_drills.churn_drill(str(tmp_path_factory.mktemp("churn")))
+
+
+def test_churn_drill_loses_nothing_byte_identical(churn):
+    for name in ("static", "autoscaled"):
+        r = churn[name]
+        assert r["lost_requests"] == 0, name
+        assert r["byte_identical"] is True, name
+        assert r["revocations_injected"] >= 2, name
+        assert r["revocation_notices"] >= 1, name  # somebody drained gracefully
+
+
+def test_churn_drill_autoscaled_beats_static_p99_and_hydrates(churn):
+    """The autoscaler's reason to exist: the spike's p99 turnaround beats
+    the static fleet's; and survivors warm up from the cross-host prefix
+    store instead of re-prefilling."""
+    static, auto = churn["static"], churn["autoscaled"]
+    assert auto["workers_peak"] > static["workers_peak"]
+    assert auto["p99_ttft_s"] < static["p99_ttft_s"]
+    assert auto["prefix_store_pages_hydrated"] > 0

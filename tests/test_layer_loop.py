@@ -53,6 +53,16 @@ def _row_vectors(temps):
             i32([-1] * B), i32([64] * B))
 
 
+def _prefill_rows_logits(model, params, cache, tokens, offsets, lengths):
+    """The row-gathered prefill program's last-token logits, scattered back
+    to slots: what ``make_prefill_step`` samples from."""
+    table = cache["page_table"]
+    idx, cache, tokens, offsets, lengths = sampling._gather_rows(
+        ROWS, cache, tokens, offsets, lengths)
+    logits, cache = model.prefill_chunk(params, cache, tokens, offsets, lengths)
+    return sampling._to_slots(idx, B, table, cache, logits[:, :model.cfg.vocab_size])[0]
+
+
 def _serve(model, params, cache, unrolled):
     """Run the prefill (rows gathered), decode and verify programs in turn,
     each as the engine compiles it and in its raw-logits form; return every
@@ -69,19 +79,21 @@ def _serve(model, params, cache, unrolled):
         # rows 0 and 2 carry prompt tokens, so ROWS of B rows run
         tokens = jnp.asarray(rng.integers(0, vocab, (B, CHUNK)), jnp.int32)
         offsets = jnp.zeros(B, jnp.int32)
-        logits, _ = jax.jit(sampling.make_prefill_step(model, SEED, False, rows=ROWS))(
+        logits = jax.jit(lambda *a: _prefill_rows_logits(model, *a))(
             params, cache, tokens, offsets, lengths)
-        nxt, done, cache = jax.jit(sampling.make_prefill_step(model, SEED, True, rows=ROWS))(
+        nxt, done, cache = jax.jit(sampling.make_prefill_step(model, SEED, rows=ROWS))(
             params, cache, tokens, offsets, lengths, *_row_vectors(temps))
         out["prefill_rows"] = (logits, nxt, done, cache)
 
     # decode: every row, at its own depth
     pos = jnp.maximum(lengths, 1)
     step_tokens = nxt[:, None]
-    decode_logits = sampling.make_decode_step(model, SEED, False)
+    def decode_logits(*a):
+        return model.decode_step(*a)[0][:, 0, :vocab]
+
     out["scans"] = str(jax.make_jaxpr(decode_logits)(params, cache, step_tokens, pos)).count("scan[")
-    logits, _ = jax.jit(decode_logits)(params, cache, step_tokens, pos)
-    nxt, done, cache = jax.jit(sampling.make_decode_step(model, SEED, True))(
+    logits = jax.jit(decode_logits)(params, cache, step_tokens, pos)
+    nxt, done, cache = jax.jit(sampling.make_decode_step(model, SEED))(
         params, cache, step_tokens, pos, *_row_vectors(temps))
     out["decode"] = (logits, nxt, done, cache)
 

@@ -35,6 +35,8 @@ from repro.models import Model, ModelRuntime
 from repro.serving.engine import Request, ServeEngine
 from repro.serving.prefix_store import AsyncPublisher, PrefixStore
 
+import fleet_drills
+
 
 def _setup(seed=0):
     cfg = reduced(get_arch("ds-paper-100m"))
@@ -399,3 +401,37 @@ def test_with_retries_exhausts_then_raises_and_misses_propagate():
     with pytest.raises(FileNotFoundError):
         _with_retries(miss, key="k", clock=clk, attempts=3)
     assert calls["n"] == 1  # a miss is not transient: no retry burned
+
+
+# ------------------------------------------------------------ recovery drill
+@pytest.fixture(scope="module")
+def recovery(tmp_path_factory):
+    """Three fleets through the same revocations and flaky storage/queue
+    windows: checkpoints off, on, and on but unreadable."""
+    return fleet_drills.recovery_drill(str(tmp_path_factory.mktemp("recovery")))
+
+
+def test_recovery_drill_all_legs_lossless(recovery):
+    for leg in ("replay", "checkpoint", "sabotage"):
+        r = recovery[leg]
+        assert r["lost_requests"] == 0, leg
+        assert r["byte_identical"] is True, leg
+        assert r["revocations_injected"] >= 2, leg
+        # the flaky windows actually fired and retries rode them out
+        assert r["storage_faults"] > 0 and r["queue_faults"] > 0, leg
+
+
+def test_recovery_drill_checkpoint_resumes_sabotage_falls_back(recovery):
+    """Checkpointed drains hand their emitted tail to the resuming worker
+    instead of re-decoding it (>= 3x fewer tokens re-decoded than full
+    replay); with every record unreadable the fleet walks the fallback
+    ladder to replay instead."""
+    replay, ckpt, sab = (recovery[k] for k in ("replay", "checkpoint", "sabotage"))
+    assert replay["checkpoints_published"] == 0
+    assert replay["tokens_redecoded"] > 0  # a generation was interrupted
+    assert ckpt["checkpoints_published"] > 0
+    assert ckpt["checkpoint_resumes"] > 0
+    assert ckpt["tokens_recovered"] > 0
+    assert replay["tokens_redecoded"] >= 3 * max(ckpt["tokens_redecoded"], 1)
+    assert sab["checkpoint_fallbacks"] > 0
+    assert sab["checkpoint_resumes"] == 0

@@ -8,6 +8,7 @@ import os
 os.environ.setdefault("DS_DEBUG_INVARIANTS", "1")
 
 import jax
+import pytest
 
 import repro.launch.serve  # noqa: F401  (registers distributed-serve)
 import repro.launch.train  # noqa: F401
@@ -20,6 +21,7 @@ from repro.core import (
     VirtualClock,
 )
 from repro.core.queue import DurableQueue
+from repro.launch.serve import _build_engine
 from repro.launch.train import build_model
 from repro.serving.engine import Request, ServeEngine
 
@@ -62,6 +64,17 @@ def _reference_outputs(job, prompts, max_new):
                 for i, p in enumerate(prompts)])
     eng.run_to_completion()
     return {r.uid: r.output for r in eng.finished}
+
+
+@pytest.mark.parametrize("key,value", [
+    ("dispatch_mode", "grouped"), ("sample_on_device", False),
+    ("refill_policy", "drain"), ("prefix_match", "page"),
+])
+def test_job_with_retired_engine_option_is_refused(key, value):
+    """A job dict is outside input: an option the engine no longer has is
+    refused by name, not silently served by the one remaining path."""
+    with pytest.raises(ValueError, match=key):
+        _build_engine(dict(SHARED, **{key: value}), ctx=None)
 
 
 def test_stream_payload_serves_acks_and_drains(tmp_path):

@@ -124,37 +124,6 @@ def test_randomized_interleaving_invariants_and_one_shot_parity():
     assert preempted_somewhere, "pool never came under pressure — weak test"
 
 
-def test_drain_refill_policy_admits_only_into_empty_batch():
-    """refill_policy='drain' (the benchmark baseline) must not admit
-    while any slot is active, and still complete everything correctly."""
-    cfg, model, params = _setup(1)
-    # ragged budgets: slots free at different ticks, so continuous refill
-    # genuinely beats waiting for the batch to drain
-    reqs = [Request(uid=f"r{i}", prompt=[i + 1, i + 2],
-                    max_new_tokens=2 + (i % 3) * 2)
-            for i in range(5)]
-    cont = ServeEngine(model, params, max_batch=2, max_len=32)
-    cont.submit([Request(uid=r.uid, prompt=list(r.prompt),
-                         max_new_tokens=r.max_new_tokens) for r in reqs])
-    cont.run_to_completion()
-    want = {r.uid: r.output for r in cont.finished}
-
-    eng = ServeEngine(model, params, max_batch=2, max_len=32,
-                      refill_policy="drain")
-    eng.submit(reqs)
-    while eng.pending or eng.scheduler.has_active():
-        active_before = sum(1 for s in eng.slots if s.req is not None)
-        admitted_before = eng.stats.admissions
-        eng.step()
-        if active_before > 0:
-            assert eng.stats.admissions == admitted_before, (
-                "drain policy admitted into a non-empty batch"
-            )
-    assert {r.uid: r.output for r in eng.finished} == want
-    # drain waits for the whole batch: strictly more ticks than continuous
-    assert eng.stats.ticks > cont.stats.ticks
-
-
 def test_prefill_token_budget_interleaves_and_stays_token_parity():
     """A finite per-tick prefill budget spreads prompt ingestion over
     ticks (more prefill dispatches, mid-prefill rows sit decode out) but
@@ -276,7 +245,7 @@ def test_prefill_budget_refused_where_it_would_corrupt_or_noop():
     """A finite budget holds rows mid-prefill across decode ticks: on
     recurrent state the batch-wide dispatch would corrupt the held row's
     recurrence, and without the fused prefill path the knob is inert —
-    both must be refused at construction, like grouped mode on SSM."""
+    both must be refused at construction."""
     import pytest
 
     cfg = reduced(get_arch("mamba2-1.3b"))
@@ -288,7 +257,7 @@ def test_prefill_budget_refused_where_it_would_corrupt_or_noop():
     cfg2, model2, params2 = _setup()
     with pytest.raises(ValueError, match="fused prefill"):
         ServeEngine(model2, params2, max_batch=2, max_len=32,
-                    dispatch_mode="grouped", prefill_token_budget=4)
+                    prefill_chunk=0, prefill_token_budget=4)
     with pytest.raises(ValueError, match="positive"):
         ServeEngine(model2, params2, max_batch=2, max_len=32,
                     prefill_token_budget=0)

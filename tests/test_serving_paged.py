@@ -132,7 +132,7 @@ def test_paged_rejected_for_unpageable_arch():
 def test_stop_token_finishes_early_on_device():
     """Satellite: the fused dispatch's done mask must finish a request the
     moment it emits its stop token (kept in the output), in dense and
-    paged modes and in the grouped baseline, identically."""
+    paged modes identically."""
     cfg, model, params = _setup(seed=4)
     probe = ServeEngine(model, params, max_batch=1, max_len=32)
     probe.submit([Request(uid="p", prompt=[1, 2, 3], max_new_tokens=6)])
@@ -143,7 +143,6 @@ def test_stop_token_finishes_early_on_device():
     for name, kwargs in (
         ("dense", {}),
         ("paged", dict(cache_mode="paged", page_size=8, total_pages=4)),
-        ("grouped", dict(dispatch_mode="grouped")),
     ):
         e = ServeEngine(model, params, max_batch=2, max_len=32, **kwargs)
         e.submit([Request(uid="s", prompt=[1, 2, 3], max_new_tokens=6,
@@ -152,20 +151,7 @@ def test_stop_token_finishes_early_on_device():
         if name == "paged":
             assert e.pages_in_use == 0  # freed the moment the mask fired
     assert outs["dense"] == free_run[:3], (outs["dense"], free_run)
-    assert outs["dense"] == outs["paged"] == outs["grouped"]
-
-
-def test_stop_token_host_sampling_fallback():
-    """sample_on_device=False re-derives the stop condition on host."""
-    cfg, model, params = _setup(seed=4)
-    probe = ServeEngine(model, params, max_batch=1, max_len=32)
-    probe.submit([Request(uid="p", prompt=[1, 2, 3], max_new_tokens=6)])
-    free_run = probe.run_to_completion()[0].output
-    e = ServeEngine(model, params, max_batch=1, max_len=32,
-                    sample_on_device=False)
-    e.submit([Request(uid="s", prompt=[1, 2, 3], max_new_tokens=6,
-                      stop_token=free_run[1])])
-    assert e.run_to_completion()[0].output == free_run[:2]
+    assert outs["dense"] == outs["paged"]
 
 
 # ------------------------------------------------------ model-level kernel path

@@ -208,18 +208,6 @@ def test_paged_mla(mla):
     assert outputs(eng) == want
 
 
-def test_host_sampling(gqa):
-    model, params = gqa
-    ps = prompts(6, seed=41, lo=20, hi=300)
-
-    def make():
-        return requests(ps, temperature=0.7)
-
-    want = references(model, params, make, WIDE, sample_on_device=False)
-    eng = paged(model, params, make(), WIDE, sample_on_device=False)
-    assert outputs(eng) == want
-
-
 def test_prefill_worker_hands_off(gqa, tmp_path):
     """A prefill-role engine ingests with compacted dispatches; a decode
     engine takes its handoffs and emits the dense engine's tokens."""
@@ -249,20 +237,17 @@ def test_prefill_worker_hands_off(gqa, tmp_path):
     assert dec.handoff_fallbacks == 0
 
 
-@pytest.mark.parametrize("on_device", [True, False], ids=["device", "host"])
-def test_model_runs_R_by_C(gqa, on_device):
+def test_model_runs_R_by_C(gqa):
     """The prefill program's model inputs are (R, C): its hidden states
     are (R, C, d) and never (B, C, d); its outputs stay (B, ...)."""
     model, params = gqa
     B, C = WIDE
     eng = ServeEngine(model, params, max_batch=B, max_len=MAX_LEN, prefill_chunk=C,
-                      cache_mode="paged", page_size=PAGE, total_pages=64,
-                      sample_on_device=on_device)
+                      cache_mode="paged", page_size=PAGE, total_pages=64)
     R, d = eng.prefill_rows, model.cfg.d_model
     vec = np.zeros((B,), np.int32)
-    args = [params, eng.cache, np.zeros((B, C), np.int32), vec, vec]
-    if on_device:
-        args += [np.zeros((B,), np.float32), vec, vec, vec, vec]
+    args = [params, eng.cache, np.zeros((B, C), np.int32), vec, vec,
+            np.zeros((B,), np.float32), vec, vec, vec, vec]
     text = str(jax.make_jaxpr(eng._prefill)(*args))
     assert f"f32[{R},{C},{d}]" in text
     assert f"[{B},{C},{d}]" not in text

@@ -74,6 +74,39 @@ def test_spec_byte_identical_to_nonspec_both_proposers():
     assert eng_d.draft_tokens_accepted < eng_d.draft_tokens_proposed
 
 
+def test_ngram_cuts_dispatches_per_token_on_decode_heavy_traffic():
+    """Short prompts, long generations, low batch: the latency-bound shape
+    where nearly every dispatch is a decode tick.  The ngram proposer
+    must land >= 2 tokens per verify dispatch and strictly cut target
+    dispatches per token against the plain engine, emitting the same
+    tokens."""
+    cfg, model, params = _setup()
+
+    def reqs():
+        rng = np.random.default_rng(11)
+        out = []
+        for i in range(4):
+            n = int(rng.integers(4, 12))
+            prompt = [int(t) for t in rng.integers(1, 200, size=n)]
+            out.append(Request(uid=f"d{i}", prompt=prompt, max_new_tokens=24))
+        return out
+
+    def per_token(eng):
+        return eng.dispatches / (eng.tokens_emitted + eng.prompt_tokens_ingested)
+
+    kw = dict(max_batch=2, max_len=128, prefill_chunk=8, cache_mode="paged", page_size=16)
+    off = ServeEngine(model, params, **kw)
+    ngram = ServeEngine(model, params, speculative="ngram", spec_k=8, **kw)
+    for eng in (off, ngram):
+        eng.submit(reqs())
+        eng.run_to_completion()
+    assert ({r.uid: r.output for r in ngram.finished}
+            == {r.uid: r.output for r in off.finished})
+    assert ngram.draft_tokens_accepted > 0
+    assert ngram.stats.snapshot()["accepted_per_dispatch"] >= 2.0
+    assert per_token(ngram) < per_token(off)
+
+
 def test_spec_works_on_dense_cache_too():
     """Rewind is a frontier move, not a page operation, so speculation
     also runs (and stays byte-identical) on the dense cache."""

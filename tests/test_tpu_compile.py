@@ -110,11 +110,11 @@ def _compile_dispatch(sharding, dispatch, dtype):
     vec = lambda dt: _spec(sharding, (B,), dt)  # noqa: E731
     # (step, tokens per row, per-row int vectors before the temperatures)
     step, T, n_pos = {
-        "decode": (sampling.make_decode_step(model, 0, True), 1, 1),
-        "prefill": (sampling.make_prefill_step(model, 0, True), 16, 2),
+        "decode": (sampling.make_decode_step(model, 0), 1, 1),
+        "prefill": (sampling.make_prefill_step(model, 0), 16, 2),
         "verify": (sampling.make_verify_step(model, 0), 5, 2),
         # the paged engine's prefill: the rows that prefill gathered in-program
-        "prefill_rows": (sampling.make_prefill_step(model, 0, True, rows=2), 16, 2),
+        "prefill_rows": (sampling.make_prefill_step(model, 0, rows=2), 16, 2),
     }[dispatch]
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(ops, "_default_interpret", lambda i: False if i is None else i)
